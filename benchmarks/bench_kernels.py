@@ -574,7 +574,11 @@ def bench_shard(out=print, json_path="BENCH_shard.json"):
     from repro.optim import sgd
 
     n = jax.device_count()
-    assert n > 1, "bench_shard needs a multi-device runtime (CLI forces 4)"
+    if n < 2:
+        raise SystemExit(
+            f"--shard needs several devices, JAX found {n} (on a CPU host: "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=4)"
+        )
 
     def subtree_bytes(shape_tree, shard_tree):
         shapes = jax.tree_util.tree_leaves(shape_tree)
@@ -800,24 +804,6 @@ if __name__ == "__main__":
     elif args.fuse:
         bench_fuse(json_path=args.json or "BENCH_fuse.json")
     elif args.shard:
-        if jax.device_count() < 2:
-            # jax is initialized by now — device count is baked in.  Re-exec
-            # with a forced 4-device CPU topology instead.
-            import os
-            import subprocess
-            import sys
-
-            env = dict(
-                os.environ,
-                JAX_PLATFORMS="cpu",
-                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=4"),
-            )
-            sys.exit(subprocess.call(
-                [sys.executable, __file__, "--shard",
-                 "--json", args.json or "BENCH_shard.json"],
-                env=env,
-            ))
         bench_shard(json_path=args.json or "BENCH_shard.json")
     else:
         main()

@@ -364,12 +364,10 @@ def _data_mesh():
     """1-axis mesh over every visible device (the multi-device CI lane
     forces 4 host devices via XLA_FLAGS)."""
     import jax
-    import numpy as np
-    from jax.sharding import Mesh
 
-    from repro.launch.mesh import DATA_AXIS
+    from repro.launch.mesh import DATA_AXIS, make_mesh
 
-    return Mesh(np.asarray(jax.devices()), (DATA_AXIS,))
+    return make_mesh((jax.device_count(),), (DATA_AXIS,))
 
 
 def _cce_shardings(mesh, table):
@@ -656,7 +654,7 @@ def run_audit(config: str, *, with_cost: bool = False, budget=None) -> Report:
             "n_eqns_by_primitive": {
                 k: v for k, v in sorted(
                     primitive_counts(prog.closed).items()
-                ) if k in ("pallas_call", "scan", "while", "cond", "pjit")
+                ) if k in ("pallas_call", "scan", "while", "cond", "jit")
             },
         })
     if with_cost and budget is not None:

@@ -34,17 +34,17 @@ def label_matches(label: str, names: tuple[str, ...]) -> bool:
     return any(re.search(rf"\['{re.escape(n)}'\]", label) for n in names)
 
 
-def _unwrap_trivial_pjit(closed):
+def _unwrap_trivial_jit(closed):
     """``make_jaxpr`` of an already-jitted fn yields a one-equation
-    wrapper — every invar feeds a single pjit call — which defeats
+    wrapper — every invar feeds a single jit call — which defeats
     use/def analysis: every input looks consumed.  When the wrapper is
-    exactly that trivial (one pjit eqn fed the outer invars in order),
+    exactly that trivial (one jit eqn fed the outer invars in order),
     hand rules the body jaxpr instead; positional labeling still holds
-    because pjit binds its operands 1:1."""
+    because jit binds its operands 1:1."""
     jaxpr = closed.jaxpr
     if (
         len(jaxpr.eqns) == 1
-        and jaxpr.eqns[0].primitive.name == "pjit"
+        and jaxpr.eqns[0].primitive.name == "jit"
         and tuple(map(id, jaxpr.eqns[0].invars)) == tuple(map(id, jaxpr.invars))
     ):
         inner = jaxpr.eqns[0].params.get("jaxpr")
@@ -83,7 +83,7 @@ class AuditProgram:
         donation settings are used, otherwise the capture jits it with
         exactly these argnums.
         """
-        closed = _unwrap_trivial_pjit(jax.make_jaxpr(fn)(*args))
+        closed = _unwrap_trivial_jit(jax.make_jaxpr(fn)(*args))
         labels = _tree_labels(args)
         if len(labels) != len(closed.jaxpr.invars):
             # tracing didn't flatten 1:1 (static args, captured trees):

@@ -4,7 +4,7 @@ Every program-level invariant this repo has earned (one pallas launch per
 step, zero pointer gathers on device, donated state, no host callbacks)
 is a statement about the *traced program*, and every one of them needs
 the same traversal: visit each equation of a (closed) jaxpr, then recurse
-into every sub-jaxpr hiding in equation params — pjit bodies, scan/while
+into every sub-jaxpr hiding in equation params — jit bodies, scan/while
 bodies, cond branches, custom_vjp call jaxprs, shard_map bodies, pallas
 kernel bodies.  Rules must never hand-roll that recursion (the pre-PR-6
 copies in tests drifted exactly this way); they consume ``walk`` /

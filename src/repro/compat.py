@@ -1,59 +1,34 @@
-"""Compatibility shims for jax API drift.
+"""The one home of ``jax.experimental`` and of the sharding call shapes.
 
-The repo targets the current jax API surface (``jax.shard_map``,
-``jax.sharding.set_mesh``, dict-valued ``Compiled.cost_analysis()``), but
-must also run on the 0.4.x line this container ships.  Every call site
-that would otherwise need a version check imports from here instead.
+The repo targets the installed jax (0.9): ``jax.shard_map``,
+``jax.sharding.set_mesh`` and dict-valued ``Compiled.cost_analysis()``.
+Call sites import from here so the ``no-raw-experimental`` source rule
+can keep every experimental import in this file.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-try:  # jax >= 0.4.35 exports it at top level as jax.shard_map
-    shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off.
+    """``shard_map`` with varying-manual-axes checking off: bodies that
+    call pallas kernels (custom_vjp around ``pallas_call``) carry no
+    replication rule, so the checker would refuse them."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
-    Bodies that call pallas kernels (custom_vjp around ``pallas_call``)
-    have no replication rule on the 0.4.x line, so the checker refuses
-    them outright.  The flag was renamed ``check_rep`` -> ``check_vma``
-    across jax versions; try the modern spelling first."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 # pallas has no stable top-level home yet; this is the ONE sanctioned
 # import of it (kernels do `from repro.compat import pallas as pl`, and
 # the no-raw-experimental source rule keeps it that way)
 from jax.experimental import pallas  # noqa: E402,F401
 
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """``jax.sharding.set_mesh`` (new) or the ``with mesh:`` thread-local
-    context (0.4.x) — both make ``mesh`` ambient for jit/PartitionSpec."""
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+#: makes ``mesh`` ambient for jit/PartitionSpec (a context manager)
+set_mesh = jax.sharding.set_mesh
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()``: newer jax returns one dict,
-    older returns a list with one dict per partition."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``Compiled.cost_analysis()`` as a plain dict (None -> {})."""
+    return dict(compiled.cost_analysis() or {})

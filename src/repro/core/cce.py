@@ -471,28 +471,36 @@ class CCE:
         tabs = params["tables"]
 
         def _chunk_assign(main_rows, ids_chunk):
-            main = jax.vmap(lambda t, r: t[r])(tabs[:, 0], main_rows)
-            helper = jax.vmap(lambda t, r: t[r])(
-                tabs[:, 1], self._helper_rows({"hs": hs}, ids_chunk)
-            )
-            emb = main + helper  # (c, n, dsub)
-            return jnp.stack(
-                [
-                    km.assign(emb[i], centroids[i], use_kernel=use_kernel)
-                    for i in range(self.c)
-                ]
-            )
+            helper_rows = self._helper_rows({"hs": hs}, ids_chunk)
+
+            # one column at a time, so one (n, k) distance block is live
+            def column(i, out):
+                emb = tabs[i, 0][main_rows[i]] + tabs[i, 1][helper_rows[i]]
+                a = km.assign(emb, centroids[i], use_kernel=use_kernel)
+                return out.at[i].set(a)
+
+            return jax.lax.fori_loop(0, self.c, column, jnp.zeros_like(main_rows))
 
         def per_shard(ids_local, ptr_local):
             n_local = ids_local.shape[0]
             step = chunk_size if chunk_size and chunk_size < n_local else n_local
-            outs = [
-                _chunk_assign(
-                    ptr_local[:, s : s + step], ids_local[s : s + step]
+            if step == n_local:
+                return _chunk_assign(ptr_local, ids_local)
+
+            # a loop over chunks keeps one chunk's (n, k) distances live;
+            # the last start clamps to n_local - step, so the tail chunk
+            # overlaps its neighbour and rewrites the same assignments
+            def body(j, out):
+                s = jnp.minimum(j * step, n_local - step)
+                rows = jax.lax.dynamic_slice_in_dim(ptr_local, s, step, axis=1)
+                ids_c = jax.lax.dynamic_slice_in_dim(ids_local, s, step)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, _chunk_assign(rows, ids_c), s, axis=1
                 )
-                for s in range(0, n_local, step)
-            ]
-            return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+            return jax.lax.fori_loop(
+                0, -(-n_local // step), body, jnp.zeros_like(ptr_local)
+            )
 
         ptr_new = compat.shard_map_unchecked(
             per_shard, mesh=mesh, in_specs=(P(axis_name), P(None, axis_name)),

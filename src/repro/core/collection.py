@@ -552,8 +552,9 @@ class EmbeddingCollection:
         AND gradient are bit-identical to the unsharded launch (tested
         in test_sharded_lookup.py).
 
-        ``check_rep`` is off (no replication rule for pallas_call on
-        jax 0.4.x) — out_specs are correct by the argument above.
+        Varying-axes checking is off (``compat.shard_map_unchecked``:
+        pallas_call carries no replication rule) — out_specs are correct
+        by the argument above.
         """
         from repro import compat
 

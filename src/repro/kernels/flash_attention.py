@@ -44,8 +44,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, scale: float, causal: bool,
 
     def body(j, carry):
         acc, m, ell = carry
-        k = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (bq, bk)
         if causal:

@@ -1,8 +1,14 @@
-"""Production mesh construction and the canonical axis names.
+"""Mesh construction and the canonical axis names.
 
-A function (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first jax
 init, smoke tests must keep seeing 1 device.
+
+``make_mesh`` is the ONE place a ``jax.sharding.Mesh`` is built: every
+axis is ``AxisType.Auto`` (GSPMD propagation), which is what every
+jit/shard_map call site in the repo is written for — jax's own
+``make_mesh`` defaults to Explicit axes, under which a jit that is not
+entered into the mesh refuses mesh-sharded operands.
 
 ``DATA_AXIS`` / ``MODEL_AXIS`` are the ONE definition of the mesh axis
 names: every shard_map / PartitionSpec call site routes through them (or
@@ -12,10 +18,20 @@ literals, so the audit's source rules can grep one symbol.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 POD_AXIS = "pod"
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """An all-Auto-axes mesh of ``shape`` over ``devices`` (default: the
+    first prod(shape) of ``jax.devices()``; a described topology's
+    ``devices`` for chip-less compiles)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,18 +39,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod \
         else (DATA_AXIS, MODEL_AXIS)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small (data, model) mesh over however many (CPU) devices exist —
-    tests/examples.  ``model`` is honoured exactly (the slab shard count
-    must divide k); ``data`` shrinks to fit the device count."""
-    n = len(jax.devices())
+def make_host_mesh(data: int = 1, model: int = 1, *, devices=None):
+    """Small (data, model) mesh over the host's devices (or ``devices``).
+    ``model`` is honoured exactly (the slab shard count must divide k);
+    ``data`` shrinks to fit the device count."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     if model > n:
         raise ValueError(f"model={model} exceeds device count {n}")
     data = max(1, min(data, n // model))
-    return jax.make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+    return make_mesh((data, model), (DATA_AXIS, MODEL_AXIS), devices=devices)
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

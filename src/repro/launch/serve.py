@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.cache import use_compile_cache
 from repro.models import lm
 from repro.serve import Request, ServeEngine
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--max-tokens", type=int, default=12)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get_reduced(args.arch)
     params, buffers = lm.init(jax.random.PRNGKey(args.seed), cfg)

@@ -1,16 +1,21 @@
 """Training launcher.
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
         --steps 50 --emb cce --ckpt-dir /tmp/ckpt
 
-``--reduced`` runs the CPU-sized family variant (what the smoke tests use);
-without it the full config lowers for whatever devices exist (on a real pod
-this is the entry point — same code path the dry-run proves out).
-DLRM (the paper's model): ``--arch dlrm``.
+``--reduced`` (the default) runs the CPU-sized family variant (what the
+smoke tests use).  DLRM (the paper's model) is ``--arch dlrm``; with
+``--no-reduced`` it trains ``configs.dlrm_criteo.CONFIG`` at the
+published Criteo widths (26 Kaggle-vocabulary tables, emb_dim 16, MLPs
+(512,256,64,16)/(512,256,1), CCE cap 8000) — the chip entry point:
+
+    PYTHONPATH=src python -m repro.launch.train --arch dlrm --no-reduced \
+        --steps 5 --batch 2048
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -18,11 +23,13 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.data import clickstream_batches, lm_token_batches, ClickstreamConfig
+from repro.launch.cache import use_compile_cache
 from repro.models import dlrm, lm
 from repro.obs import RunLog, TelemetryConfig
 from repro.obs.runlog import default_manifest
 from repro.optim import adamw, sgd, cosine_schedule
 from repro.optim.remap import remap_opt_state
+from repro.stream import make_step_cell_counter
 from repro.train.freq import IdFrequencyTracker
 from repro.train.transition import transition_table
 from repro.train.loop import (
@@ -134,12 +141,14 @@ def build_dlrm_sharded_trainer(cfg, args, *, model: int, data_shards: int = 1):
     def lr_fn(step):
         return jnp.float32(args.lr)
 
-    track = args.emb == "cce"
+    # the sharded step carries no sketch counter: the tracker folds the
+    # raw ids it reads off the batch on the host (Trainer.observe)
+    tracker = dlrm_tracker(cfg, args)
     telemetry, obs_kw = _obs_kit(args, "dlrm_sharded")
     step, _, (state_shardings, _) = build_dlrm_train_step(
         cfg, mesh, batch_size=args.batch, accum=args.accum,
         optimizer=optimizer, lr_fn=lr_fn, static_buffers=static,
-        with_sparse=track,  # the host tracker reads raw ids off the batch
+        with_sparse=tracker is not None,
         telemetry=telemetry,
     )
     state = jax.tree.map(
@@ -154,7 +163,6 @@ def build_dlrm_sharded_trainer(cfg, args, *, model: int, data_shards: int = 1):
         ),
         translator,
     )
-    tracker = IdFrequencyTracker(cfg.vocab_sizes) if track else None
 
     def cluster_fn(key, params, buffers, opt):
         return dlrm.cluster_tables(
@@ -165,7 +173,7 @@ def build_dlrm_sharded_trainer(cfg, args, *, model: int, data_shards: int = 1):
     return Trainer(
         step, state, static, data,
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-        cluster_fn=cluster_fn if track else None,
+        cluster_fn=cluster_fn if tracker is not None else None,
         cluster_every=args.cluster_every, id_tracker=tracker,
         translator=translator, accum=args.accum,
         failures=FailureInjector(tuple(args.fail_at)),
@@ -176,47 +184,83 @@ def build_dlrm_sharded_trainer(cfg, args, *, model: int, data_shards: int = 1):
     )
 
 
-def build_dlrm_trainer(args):
+def dlrm_config(args):
+    """The DLRM config the flags select: ``--reduced`` (default) the small
+    synthetic config, ``--no-reduced`` the published Criteo widths.  The
+    supertable codebook is padded to a multiple of ``--model-shards``."""
     from repro.configs import dlrm_criteo
 
     model = max(1, getattr(args, "model_shards", 1))
-    cfg = dlrm_criteo.reduced(
-        emb_method=args.emb, cap=args.emb_cap, k_multiple=model,
+    cap = getattr(args, "emb_cap", None)
+    if getattr(args, "reduced", True):
+        return dlrm_criteo.reduced(
+            emb_method=args.emb, cap=cap or 512, k_multiple=model,
+        )
+    return dataclasses.replace(
+        dlrm_criteo.CONFIG, emb_method=args.emb,
+        emb_param_cap=cap or dlrm_criteo.CONFIG.emb_param_cap,
+        emb_k_multiple=model,
     )
+
+
+def dlrm_tracker(cfg, args):
+    """The id-frequency tracker feeding the transition's k-means (None
+    without CCE).  At the published vocabularies the dense histograms
+    would hold ~270 MB on the host (DESIGN.md §5), so full width uses
+    the sketch tracker at vocabulary-independent memory."""
+    from repro.configs import dlrm_criteo
+
+    if args.emb != "cce":
+        return None
+    stream = None if getattr(args, "reduced", True) else dlrm_criteo.STREAM
+    return dlrm.make_id_tracker(cfg, stream)
+
+
+def dlrm_train_step(cfg, args, static_buffers, tracker, telemetry=None):
+    """(donated jitted 1-device step, optimizer) — what the 1-device
+    trainer runs; a sketch tracker's cell counting rides inside the step
+    (no extra dispatch; the dense tracker contributes nothing)."""
+    optimizer = sgd(momentum=args.momentum)  # paper default: plain SGD
+
+    def lr_fn(step):
+        return jnp.float32(args.lr)
+
+    def loss_fn(p, b, mb):
+        return dlrm.bce_loss(p, b, cfg, mb), {}
+
+    step = make_train_step(loss_fn, optimizer, lr_fn, static_buffers,
+                           accum=args.accum, telemetry=telemetry,
+                           sketch_fn=make_step_cell_counter(tracker),
+                           donate=True)
+    return step, optimizer
+
+
+def build_dlrm_trainer(args):
+    cfg = dlrm_config(args)
+    model = max(1, getattr(args, "model_shards", 1))
     if model > 1:
         return build_dlrm_sharded_trainer(
             cfg, args, model=model,
             data_shards=max(1, getattr(args, "data_shards", 1)),
         )
-    key = jax.random.PRNGKey(args.seed)
-    params, buffers = dlrm.init(key, cfg)
+    params, buffers = dlrm.init(jax.random.PRNGKey(args.seed), cfg)
     dyn, static = split_buffers(buffers)
-    optimizer = sgd(momentum=args.momentum)  # paper default: plain SGD
-    def lr_fn(step):
-        return jnp.float32(args.lr)
-
-
-    def loss_fn(p, b, mb):
-        return dlrm.bce_loss(p, b, cfg, mb), {}
-
+    tracker = dlrm_tracker(cfg, args)
     telemetry, obs_kw = _obs_kit(args, "dlrm")
-    step = make_train_step(loss_fn, optimizer, lr_fn, static, accum=args.accum,
-                           telemetry=telemetry)
+    step, optimizer = dlrm_train_step(cfg, args, static, tracker, telemetry)
     state = init_state(params, optimizer, dyn)
     data = clickstream_batches(
         ClickstreamConfig(vocab_sizes=cfg.vocab_sizes, seed=args.seed), args.batch
     )
-
-    tracker = IdFrequencyTracker(cfg.vocab_sizes) if args.emb == "cce" else None
 
     def cluster_fn(key, params, buffers, opt):
         return dlrm.cluster_tables(key, params, buffers, cfg, opt,
                                    id_counts=tracker.counts)
 
     return Trainer(
-        jax.jit(step, donate_argnums=(0,)), state, static, data,
+        step, state, static, data,
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-        cluster_fn=cluster_fn if args.emb == "cce" else None,
+        cluster_fn=cluster_fn if tracker is not None else None,
         cluster_every=args.cluster_every, id_tracker=tracker,
         accum=args.accum,
         failures=FailureInjector(tuple(args.fail_at)),
@@ -227,10 +271,11 @@ def build_dlrm_trainer(args):
     )
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dlrm")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--seq", type=int, default=64)
@@ -243,7 +288,8 @@ def main():
     ap.add_argument("--model-shards", type=int, default=1)
     ap.add_argument("--data-shards", type=int, default=1)
     ap.add_argument("--emb", default="cce")
-    ap.add_argument("--emb-cap", type=int, default=512)
+    # default: the config's own cap (512 reduced, 8000 at Criteo widths)
+    ap.add_argument("--emb-cap", type=int, default=None)
     ap.add_argument("--cluster-every", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
@@ -255,10 +301,18 @@ def main():
     ap.add_argument("--obs", default=None, metavar="RUN.jsonl")
     ap.add_argument("--profile-steps", type=int, nargs=2, default=None)
     ap.add_argument("--profile-dir", default="profile")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main():
+    args = parse_args()
+    use_compile_cache()
 
     if args.arch == "dlrm":
         trainer = build_dlrm_trainer(args)
+    elif not args.reduced:
+        raise SystemExit("--no-reduced selects the published DLRM widths; "
+                         "LM families train reduced only")
     else:
         cfg = configs.get_reduced(args.arch, emb_method=args.emb)
         trainer = build_lm_trainer(cfg, args)

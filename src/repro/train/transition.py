@@ -17,6 +17,8 @@ here keeps the paths from drifting.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,6 +45,21 @@ def _dense_weights(counts, d1: int) -> np.ndarray:
     if hasattr(counts, "id_weights"):
         return counts.id_weights(d1)
     return np.asarray(counts)
+
+
+@functools.lru_cache(maxsize=None)
+def _cluster_program(table, mesh, axis_name, chunk_size, use_kernel,
+                     max_points_per_centroid):
+    """``table.cluster_sharded`` as one jitted SPMD program (the one the
+    audit captures), built once per table and mesh so every later
+    transition reuses its trace and compile.  Run eagerly, shard_map
+    compiles each primitive of the k-means loop and chunked assignment
+    as a program of its own."""
+    return jax.jit(functools.partial(
+        table.cluster_sharded, mesh=mesh, axis_name=axis_name,
+        chunk_size=chunk_size, use_kernel=use_kernel,
+        max_points_per_centroid=max_points_per_centroid,
+    ))
 
 
 def transition_table(
@@ -90,11 +107,13 @@ def transition_table(
             id_weights = jnp.asarray(_dense_weights(counts, table.d1), jnp.float32)
     sharded = mesh is not None and shard_axis is not None
     if sharded:
-        new_params, new_buffers = table.cluster_sharded(
-            key, params, buffers, mesh, axis_name=shard_axis,
+        cluster = _cluster_program(
+            table, mesh, shard_axis, chunk_size, use_kernel,
+            max_points_per_centroid,
+        )
+        new_params, new_buffers = cluster(
+            key, params, buffers,
             sample_ids=sample_ids, sample_weights=sample_weights,
-            chunk_size=chunk_size, use_kernel=use_kernel,
-            max_points_per_centroid=max_points_per_centroid,
         )
     else:
         new_params, new_buffers = table.cluster(

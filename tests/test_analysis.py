@@ -143,6 +143,15 @@ def test_no_device_gather_flags_consumed_pointer_input():
     assert len(found) == 1 and "'ptr'" in found[0].where
 
 
+def test_capture_sees_through_prejitted_fn():
+    # make_jaxpr wraps an already-jitted fn in one jit equation fed every
+    # input; the capture hands rules the body, where ptr is never read
+    tree = {"ptr": jnp.zeros((4,), jnp.int32), "w": X}
+    prog = _capture(jax.jit(lambda d: d["w"] * 2.0), tree)
+    assert NoDeviceGatherOf(("ptr",)).check(prog) == []
+    assert len(DeadInput().check(prog)) == 1
+
+
 def test_no_device_gather_refuses_vacuous_pass():
     # no input named ptr at all -> the spec is mislabeled, not "clean"
     found = NoDeviceGatherOf(("ptr",)).check(_capture(lambda d: d["w"], {"w": X}))
@@ -177,7 +186,7 @@ def test_donation_coverage_refuses_undonated_program():
 
 def test_dtype_hygiene_flags_f64():
     assert DtypeHygiene().check(_capture(lambda x: x * 2.0, X)) == []
-    with jax.experimental.enable_x64():  # audit: allow-raw-experimental
+    with jax.enable_x64(True):
         bad = _capture(
             lambda x: x * 2.0, jax.ShapeDtypeStruct((4,), jnp.float64)
         )

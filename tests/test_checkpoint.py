@@ -81,7 +81,9 @@ def test_reshard_restore_other_sharding(tmp_path):
     t = _tree()
     save_checkpoint(str(tmp_path), 0, t)
     _, host, _ = load_checkpoint(str(tmp_path), template=t)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     sh = {
         "w": NamedSharding(mesh, P("data", None)),
         "opt": {"m": NamedSharding(mesh, P()), "t": NamedSharding(mesh, P())},

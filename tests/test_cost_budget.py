@@ -143,14 +143,14 @@ def _profile_of(fn, *args, **kw):
 def test_planted_fp64_upcast_blows_the_bytes_and_peak_budgets():
     n = 1 << 16
     f32 = _profile_of(lambda x: x * 2.0, jax.ShapeDtypeStruct((n,), jnp.float32))
-    with jax.experimental.enable_x64():  # audit: allow-raw-experimental
+    with jax.enable_x64(True):
         f64 = _profile_of(
             lambda x: x * 2.0, jax.ShapeDtypeStruct((n,), jnp.float64)
         )
     # the planted regression: fp64 doubles every byte metric
     assert f64.hbm_bytes == 2 * f32.hbm_bytes
     assert f64.peak_bytes == 2 * f32.peak_bytes
-    with jax.experimental.enable_x64():  # audit: allow-raw-experimental
+    with jax.enable_x64(True):
         prog = AuditProgram.capture(
             lambda x: x * 2.0, jax.ShapeDtypeStruct((n,), jnp.float64),
             name="toy",

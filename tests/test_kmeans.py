@@ -44,7 +44,9 @@ def test_assign_kernel_route():
 def test_distributed_kmeans_matches_serial_single_shard():
     """On a 1-device axis the distributed algorithm IS the serial one."""
     x, _ = _blobs(jax.random.PRNGKey(6))
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     from repro.compat import shard_map
 
     def run(xs):

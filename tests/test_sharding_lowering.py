@@ -20,8 +20,9 @@ import json
 import jax
 from repro import compat, configs
 from repro.launch import steps, hlo_cost
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 out = {}
 with compat.set_mesh(mesh):
     cfg = configs.get("qwen2-1.5b", n_layers=2, d_model=512, n_heads=4,

@@ -22,6 +22,7 @@ import pytest
 from repro.configs import dlrm_criteo
 from repro.core.cce import CCE
 from repro.data import ClickstreamConfig, clickstream_batches
+from repro.launch.mesh import make_mesh
 from repro.models import dlrm
 from repro.optim import sgd
 from repro.optim.remap import remap_opt_state
@@ -86,7 +87,7 @@ def test_cluster_kernel_path_matches_jnp(cce_state):
 
 def test_cluster_sharded_single_device_matches_serial(cce_state):
     cce, params, buffers = cce_state
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     p_s, b_s = cce.cluster_sharded(jax.random.PRNGKey(6), params, buffers, mesh)
     p_r, b_r = cce.cluster(jax.random.PRNGKey(6), params, buffers)
     np.testing.assert_allclose(
@@ -95,6 +96,39 @@ def test_cluster_sharded_single_device_matches_serial(cce_state):
     agree = (np.asarray(b_s["ptr"]) == np.asarray(b_r["ptr"])).mean()
     assert agree > 0.99
     np.testing.assert_array_equal(np.asarray(b_s["hs"]), np.asarray(b_r["hs"]))
+
+
+def test_sharded_transition_traces_once(monkeypatch, cce_state):
+    """The jitted ``cluster_sharded`` program is built once per table and
+    mesh: a second transition re-runs it without a new trace.  The state
+    starts on the mesh, as a trainer's state comes back from its step."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.train import transition
+
+    cce, params, buffers = cce_state
+    mesh = make_mesh((1,), ("data",))
+    params, buffers = jax.device_put((params, buffers), NamedSharding(mesh, P()))
+    traces = []
+    orig = CCE.cluster_sharded
+
+    def spy(self, *a, **kw):
+        traces.append(1)
+        return orig(self, *a, **kw)
+
+    monkeypatch.setattr(CCE, "cluster_sharded", spy)
+    transition._cluster_program.cache_clear()
+    p1, b1, _ = transition.transition_table(
+        cce, jax.random.PRNGKey(6), params, buffers,
+        mesh=mesh, shard_axis="data", use_kernel=False,
+    )
+    p2, b2, _ = transition.transition_table(
+        cce, jax.random.PRNGKey(7), p1, b1,
+        mesh=mesh, shard_axis="data", use_kernel=False,
+    )
+    transition._cluster_program.cache_clear()
+    assert len(traces) == 1
+    assert int(b2["epoch"]) == int(b1["epoch"]) + 1
 
 
 # --- moment remap ------------------------------------------------------------
@@ -274,7 +308,7 @@ def test_transition_uses_count_weighted_sample(cce_state, monkeypatch):
 
 def test_assign_all_sharded_matches_serial_on_one_device(cce_state):
     cce, params, buffers = cce_state
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cents = jax.random.normal(jax.random.PRNGKey(1), (cce.c, cce.k, cce.dsub))
     a_serial = cce.assign_all(params, buffers, cents, use_kernel=False)
     a_shard = cce.assign_all_sharded(
@@ -306,7 +340,8 @@ def test_cluster_sharded_on_forced_four_device_host():
         assert jax.device_count() == 4, jax.devices()
         cce = CCE(d1=303, d2=16, k=8, c=2, seed_salt=1)
         params, buffers = cce.init(jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         rng = np.random.default_rng(0)
         ids = jnp.asarray(rng.integers(0, 303, 256))
         w = jnp.asarray(rng.integers(1, 5, 256), jnp.float32)
