@@ -34,6 +34,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.collection import EmbeddingCollection, _expand_rows, bucket_rows
+from repro.obs.trace import span
 
 
 class HostTranslator:
@@ -169,8 +170,6 @@ def translate_batches(batches, translator: HostTranslator, *,
                       drop_sparse: bool = False):
     """Wrap a batch iterator with the host translation stage (the input
     pipeline runs on CPU hosts — see data/synthetic.py)."""
-    from repro.obs.trace import span
-
     for batch in batches:
         with span("translate"):
             out = translator(batch, drop_sparse=drop_sparse)

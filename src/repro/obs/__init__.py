@@ -15,9 +15,11 @@ Four pieces, split by where they run:
     save/restore, fault fires, serve latency) with restart-safe
     append-and-dedupe semantics, plus the fixed-bucket
     ``LatencyHistogram`` the serve engine feeds.
-  * ``trace``      — ``jax.named_scope``/profiler spans on the logical
-    phases (translate, dispatch, sketch-fold, transition, checkpoint)
-    and the opt-in ``ProfileWindow`` profiler-trace dump.
+  * ``trace``      — host spans (``jax.profiler.TraceAnnotation``, with
+    integer args as event stats) on the training loop's phases and the
+    sketch tracker's fold thread, listed by thread in its docstring; no
+    ``jax.named_scope`` on host phases (the transition opens its own).
+    Also the opt-in ``ProfileWindow`` profiler-trace dump.
 
 ``python -m repro.obs summarize RUN.jsonl`` renders a run log (p50/p99
 step time, loss curve, trigger/transition timeline, shard balance).

@@ -30,6 +30,8 @@ from typing import Callable
 import jax
 import numpy as np
 
+from repro.obs.trace import span
+
 
 def _to_host(tree):
     """device tree -> record leaves: 0-d values become python floats,
@@ -65,9 +67,10 @@ class MetricsPump:
         """Enqueue one step's device metrics; drains whatever fell
         ``lag`` steps behind.  ``extra`` carries host-side fields (dt)
         that ride the record without touching the device."""
-        self._ring.append((step, metrics, extra))
-        while len(self._ring) > self.lag:
-            self._drain_one()
+        with span("metrics-pump"):
+            self._ring.append((step, metrics, extra))
+            while len(self._ring) > self.lag:
+                self._drain_one()
 
     def _drain_one(self) -> None:
         step, metrics, extra = self._ring.popleft()
@@ -82,5 +85,6 @@ class MetricsPump:
     def flush(self) -> None:
         """Drain every in-flight record (blocks until the device catches
         up — the documented sync point for tests and checkpoints)."""
-        while self._ring:
-            self._drain_one()
+        with span("metrics-pump"):
+            while self._ring:
+                self._drain_one()

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 
 def cell_count_fn(sketches):
     """PURE (B, F) int32 -> (F, depth, width) int32 cell-increment counter
@@ -115,9 +117,16 @@ class AsyncFolder:
                 self._q.task_done()
 
     def submit(self, item) -> None:
+        """Enqueue ``item``; blocks while the queue is full (backpressure
+        instead of unbounded lag), inside a ``sketch-enqueue-wait`` span.
+        One producer, so trying first keeps the FIFO order."""
         if self._error is not None:
             self.flush()  # raises
-        self._q.put(item)  # bounded: backpressure instead of unbounded lag
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:
+            with span("sketch-enqueue-wait", depth=self._q.qsize()):
+                self._q.put(item)
 
     def flush(self) -> None:
         self._q.join()

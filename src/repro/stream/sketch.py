@@ -174,6 +174,10 @@ class SpaceSaving:
         self._dirty = True
         self._sorted_ids: np.ndarray | None = None
         self._sorted_slots: np.ndarray | None = None
+        # ids made resident and residents evicted since construction: the
+        # fold's trace counters, not state (no checkpoint carries them)
+        self.admitted = 0
+        self.evicted = 0
 
     def _index(self):
         if self._dirty:
@@ -204,6 +208,7 @@ class SpaceSaving:
         evictee's count back into the sketch).  Candidates descend by
         estimate, so the first non-admitting one ends the batch."""
         order = np.argsort(np.asarray(ests), kind="stable")[::-1]
+        n0 = self.n
         evicted_ids: list[int] = []
         evicted_cnt: list[float] = []
         for j in order.tolist():
@@ -220,6 +225,8 @@ class SpaceSaving:
             evicted_cnt.append(float(self.counts[s]))
             self.ids[s], self.counts[s] = i, est
             self._dirty = True
+        self.admitted += self.n - n0 + len(evicted_ids)
+        self.evicted += len(evicted_ids)
         if evicted_ids:  # one vectorized sketch push for the whole batch
             sketch.raise_to(np.asarray(evicted_ids), np.asarray(evicted_cnt))
 
@@ -283,14 +290,13 @@ class FeatureSketch:
         (with-multiplicity) ids."""
         self._ingest(raw_ids, into_sketch=True)
 
-    def fold_cells(self, delta: np.ndarray, raw_ids: np.ndarray) -> None:
-        """Async path: fold a device-computed (depth, width) cell delta
-        (the sketch update never touched the host hot path) and run the
-        id-level head/ring bookkeeping from the host batch copy.  Resident
-        ids' mass lands in the sketch too (their cells go stale-HIGH,
-        which the min/offer invariants tolerate); their exact counters
-        still get the increments."""
-        self.cms.add_cells(delta)
+    def fold_ids(self, raw_ids: np.ndarray) -> None:
+        """Async path: the id-level head/ring bookkeeping from the host
+        batch copy, once the batch's device-computed cell delta is folded
+        (``cms.add_cells``; the sketch update never touched the host hot
+        path).  Resident ids' mass lands in the sketch too (their cells go
+        stale-HIGH, which the min/offer invariants tolerate); their exact
+        counters still get the increments."""
         self._ingest(raw_ids, into_sketch=False)
 
     def _ingest(self, raw_ids: np.ndarray, *, into_sketch: bool) -> None:

@@ -33,6 +33,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.obs.trace import span
 from repro.stream.points import sample_from_counts
 from repro.stream.sketch import FeatureSketch
 
@@ -171,24 +172,41 @@ class SketchFrequencyTracker:
             self._close_window()
 
     def _fold(self, item) -> None:
+        """Fold one batch: the device-computed cells of every tracked
+        feature, then their heads and rings.  Features are independent and
+        each one's cells land before its own head bookkeeping, so phase
+        order across features leaves the state as a per-feature fold
+        would."""
         delta, cols = item
-        delta = np.asarray(delta)  # blocks the FOLD thread, not the step
-        for j, f in enumerate(self.tracked):
-            self.features[f].fold_cells(delta[j], cols[:, j])
+        with span("fold-batch"):
+            with span("fold-fetch"):
+                delta = np.asarray(delta)  # blocks the FOLD thread, not the step
+            with span("fold-cells"):
+                for j, f in enumerate(self.tracked):
+                    self.features[f].cms.add_cells(delta[j])
+            with span("fold-heads") as s:
+                heads = [self.features[f].hh for f in self.tracked]
+                a0, e0 = sum(h.admitted for h in heads), sum(h.evicted for h in heads)
+                for j, f in enumerate(self.tracked):
+                    self.features[f].fold_ids(cols[:, j])
+                s.set_metadata(admitted=sum(h.admitted for h in heads) - a0,
+                               evicted=sum(h.evicted for h in heads) - e0)
 
     def _close_window(self) -> None:
         """Window boundary: snapshot trigger statistics, then decay."""
-        self.flush()
-        self._pending_summary = self._summarize()
-        if self.config.decay != 1.0:
-            for f in self.tracked:
-                self.features[f].decay(self.config.decay)
+        with span("sketch-window-close"):
+            self.flush()
+            self._pending_summary = self._summarize()
+            if self.config.decay != 1.0:
+                for f in self.tracked:
+                    self.features[f].decay(self.config.decay)
 
     def flush(self) -> None:
         """Barrier for the async fold path (no-op otherwise) — call before
         sampling, checkpointing, or reading statistics."""
         if self._folder is not None:
-            self._folder.flush()
+            with span("sketch-flush"):
+                self._folder.flush()
 
     # --- trigger-facing statistics ----------------------------------------
 

@@ -458,8 +458,9 @@ class Trainer:
                                 "fault", step=step, dedupe=False, error=str(e),
                             )
                         raise
-                raw = next(self.data_iter)
-                batch = self._reshape_accum(raw)
+                with span("next-batch"):
+                    raw = next(self.data_iter)
+                    batch = self._reshape_accum(raw)
                 with span("dispatch"):
                     self.state, metrics = self.train_step(self.state, batch)
                 # dispatch-to-dispatch wall time (see StragglerMonitor's
@@ -513,7 +514,8 @@ class Trainer:
                     self.cluster_every and new_step % self.cluster_every == 0
                 )
                 if can_cluster and (periodic or triggered):
-                    with span("transition"):
+                    # the one phase that traces device work under its name
+                    with jax.named_scope("transition"), span("transition"):
                         if self.id_tracker is not None:  # async folds must land
                             getattr(self.id_tracker, "flush", lambda: None)()
                         key = jax.random.fold_in(

@@ -138,6 +138,21 @@ def test_spacesaving_head_is_exact_on_zipf_top():
     assert (fs.estimate(probe) >= true[probe] - 1e-9).all()
 
 
+def test_spacesaving_counts_admissions_and_evictions():
+    """The running counters the fold's ``fold-heads`` span reports."""
+    from repro.stream import SpaceSaving
+
+    cms = CountMinSketch(width=1 << 8, depth=2, seed=0)
+    hh = SpaceSaving(2)
+    # two free slots fill; 7's estimate does not beat the minimum resident
+    hh.offer(np.array([5, 6, 7]), np.array([3.0, 2.0, 1.0]), cms)
+    assert (hh.admitted, hh.evicted) == (2, 0)
+    # 8 evicts 6; 9 does not beat the new minimum (5 at 3.0)
+    hh.offer(np.array([8, 9]), np.array([4.0, 2.5]), cms)
+    assert (hh.admitted, hh.evicted) == (3, 1)
+    assert sorted(hh.head()[0].tolist()) == [5, 8]
+
+
 def test_decay_scales_and_recency_wins():
     fs = FeatureSketch(width=1 << 10, depth=4, heavy=8, ring=256, seed=0)
     old = np.repeat(np.arange(8), 50)  # old regime: ids 0..7, 50x each
