@@ -32,8 +32,10 @@ The spans that exist, by thread:
         fold-fetch          the device-to-host copy of the cell delta
         fold-cells          the count-min cell adds, every tracked feature
         fold-heads          the head and ring bookkeeping, every tracked
-                            feature; args ``admitted`` and ``evicted``,
-                            SpaceSaving's admissions and evictions
+                            feature; args ``candidates``, ``admitted``,
+                            ``evicted`` and ``rebuilds``: SpaceSaving's ids
+                            offered a slot, admissions, evictions and full
+                            rebuilds of its residency index
 
 The fold thread's spans run on the loop thread instead when the tracker
 folds synchronously.  No fold-thread span shares a name with a loop-thread

@@ -69,13 +69,20 @@ class CountMinSketch:
         # on the sync path resident head ids bypass the sketch entirely,
         # on the async fold the whole batch lands here.
         self.total = 0.0
-        self._rows = np.arange(depth)[:, None]
+        self._flat = np.arange(depth)[:, None] * width  # each row's offset
 
     def cells(self, ids: np.ndarray) -> np.ndarray:
         """(depth, n) uint32 cell index per hash row — multiply-shift on
         uint32 (wraps mod 2^32), top bits select the cell."""
-        x = np.asarray(ids).astype(np.uint32)[None, :]
-        return (self.a[:, None] * x + self.b[:, None]) >> self.shift
+        cells = self.a[:, None] * np.asarray(ids).astype(np.uint32)
+        cells += self.b[:, None]
+        cells >>= self.shift
+        return cells
+
+    def _at(self, cells: np.ndarray) -> np.ndarray:
+        """The counters at (depth, n) ``cells``, one per hash row (a flat
+        ``take``: a fraction of the cost of a broadcast 2-D index)."""
+        return self.counters.take(cells + self._flat)
 
     def add(self, ids: np.ndarray, counts: np.ndarray) -> None:
         """Conservative update for a batch of UNIQUE ids: raise each id's
@@ -88,7 +95,7 @@ class CountMinSketch:
             return
         counts = np.asarray(counts, _MASS_DTYPE)
         cells = self.cells(ids)
-        new = self.counters[self._rows, cells].min(axis=0) + counts
+        new = self._at(cells).min(axis=0) + counts
         for r in range(self.depth):
             np.maximum.at(self.counters[r], cells[r], new)
         self.total += float(counts.sum())
@@ -106,16 +113,16 @@ class CountMinSketch:
         ids = np.asarray(ids)
         if ids.size == 0:
             return
-        cells = self.cells(ids)
+        cells, counts = self.cells(ids), np.asarray(counts, _MASS_DTYPE)
         for r in range(self.depth):
-            np.maximum.at(self.counters[r], cells[r], np.asarray(counts, _MASS_DTYPE))
+            np.maximum.at(self.counters[r], cells[r], counts)
 
     def estimate(self, ids: np.ndarray) -> np.ndarray:
         """Min-row estimate: an upper bound on each id's true mass."""
         ids = np.asarray(ids)
         if ids.size == 0:
             return np.zeros(0, _MASS_DTYPE)
-        return self.counters[self._rows, self.cells(ids)].min(axis=0)
+        return self._at(self.cells(ids)).min(axis=0)
 
     def estimate_unbiased(self, ids: np.ndarray) -> np.ndarray:
         """Count-mean(-min) estimate: subtract each row's expected
@@ -132,7 +139,7 @@ class CountMinSketch:
         ids = np.asarray(ids)
         if ids.size == 0:
             return np.zeros(0, _MASS_DTYPE)
-        raw = self.counters[self._rows, self.cells(ids)]
+        raw = self._at(self.cells(ids))
         row_mass = self.counters.sum(axis=1, keepdims=True)
         noise = (row_mass - raw) / max(self.width - 1, 1)
         est = (raw - noise).mean(axis=0)
@@ -161,38 +168,50 @@ class SpaceSaving:
     """Fixed-capacity exact head counters (SpaceSaving with the sketch as
     the evicted-mass oracle).  Resident ids live in parallel arrays —
     slots [0, n) filled contiguously — so decay/state are vectorized and
-    checkpoint leaves are fixed-shape.  Residency lookup is a lazily
-    rebuilt sorted index (searchsorted per batch, O(u·log H)): admissions
-    become rare once the head stabilizes, so the rebuild amortizes away
-    and the hot path stays free of per-id python work."""
+    checkpoint leaves are fixed-shape.  Residency lookup is a sorted
+    (id, slot) index, searched once per batch (O(u·log H)).  On a Zipf
+    stream over a large vocabulary the head never stabilizes — hundreds
+    of ids a batch evict the least-count residents — so each ``offer``
+    updates the index in place with what it changed; only a wholesale
+    replacement of the slots (a checkpoint load, a dense ingest) rebuilds
+    it, and ``rebuilds`` counts those."""
 
     def __init__(self, capacity: int):
         self.capacity = capacity
         self.ids = np.full(capacity, -1, np.int64)
         self.counts = np.zeros(capacity, _MASS_DTYPE)
         self.n = 0
-        self._dirty = True
-        self._sorted_ids: np.ndarray | None = None
-        self._sorted_slots: np.ndarray | None = None
-        # ids made resident and residents evicted since construction: the
-        # fold's trace counters, not state (no checkpoint carries them)
+        self._dirty = False
+        self._sorted_ids = np.zeros(0, np.int64)
+        self._sorted_slots = np.zeros(0, np.int64)
+        # the fold's trace counters since construction, not state (no
+        # checkpoint carries them): ids offered a slot (every absent id
+        # while slots are free, then those whose estimate beat the least
+        # resident count), ids made resident, residents evicted, and full
+        # rebuilds of the residency index
+        self.candidates = 0
         self.admitted = 0
         self.evicted = 0
+        self.rebuilds = 0
 
     def _index(self):
+        """Rebuild the residency index after the slots were replaced
+        wholesale (resident ids are unique, so the sort has no ties)."""
         if self._dirty:
-            order = np.argsort(self.ids[: self.n], kind="stable")
+            order = np.argsort(self.ids[: self.n])
             self._sorted_ids = self.ids[: self.n][order]
             self._sorted_slots = order
             self._dirty = False
+            self.rebuilds += 1
 
     def split_resident(self, ids: np.ndarray):
         """-> (slot index per id, resident mask) for a batch of ids."""
         ids = np.asarray(ids, np.int64)
+        self._index()
         if self.n == 0:
             return np.full(ids.shape, -1, np.int64), np.zeros(ids.shape, bool)
-        self._index()
-        pos = np.clip(np.searchsorted(self._sorted_ids, ids), 0, self.n - 1)
+        pos = np.searchsorted(self._sorted_ids, ids)
+        np.minimum(pos, self.n - 1, out=pos)
         hit = self._sorted_ids[pos] == ids
         return np.where(hit, self._sorted_slots[pos], -1), hit
 
@@ -201,34 +220,91 @@ class SpaceSaving:
         callers pass unique ids)."""
         self.counts[slots] += np.asarray(counts, _MASS_DTYPE)
 
+    def _least(self, m: int, floor: float) -> np.ndarray:
+        """Slots of the ``m`` least residents in (count, slot) order —
+        the order in which repeated ``argmin`` calls pick them.  ``floor``
+        is the least count; residents often share it, and then no
+        partition is needed."""
+        c = self.counts[: self.n]
+        at = np.flatnonzero(c == floor)
+        if at.size >= m:
+            return at[:m]
+        t = c[np.argpartition(c, m - 1)[m - 1]]  # the m-th least count
+        below = np.flatnonzero(c < t)
+        below = below[np.argsort(c[below], kind="stable")]
+        return np.concatenate([below, np.flatnonzero(c == t)[: m - below.size]])
+
     def offer(self, ids: np.ndarray, ests: np.ndarray, sketch: CountMinSketch):
         """SpaceSaving admission for NON-resident ids with sketch-estimate
-        ``ests``: fill free slots first, then evict the minimum-count
-        resident when the candidate's estimate exceeds it (pushing the
-        evictee's count back into the sketch).  Candidates descend by
-        estimate, so the first non-admitting one ends the batch."""
-        order = np.argsort(np.asarray(ests), kind="stable")[::-1]
-        n0 = self.n
-        evicted_ids: list[int] = []
-        evicted_cnt: list[float] = []
-        for j in order.tolist():
-            i, est = int(ids[j]), float(ests[j])
-            if self.n < self.capacity:
-                self.ids[self.n], self.counts[self.n] = i, est
-                self.n += 1
-                self._dirty = True
-                continue
-            s = int(np.argmin(self.counts))
-            if est <= self.counts[s]:
-                break  # candidates are descending: nothing else admits
-            evicted_ids.append(int(self.ids[s]))
-            evicted_cnt.append(float(self.counts[s]))
-            self.ids[s], self.counts[s] = i, est
-            self._dirty = True
-        self.admitted += self.n - n0 + len(evicted_ids)
-        self.evicted += len(evicted_ids)
-        if evicted_ids:  # one vectorized sketch push for the whole batch
-            sketch.raise_to(np.asarray(evicted_ids), np.asarray(evicted_cnt))
+        ``ests``, taken in descending estimate order: fill free slots
+        first, then each candidate evicts the least-count resident (the
+        lowest slot among equal counts) while its estimate exceeds that
+        count, pushing the evictee's count back into the sketch.
+
+        One pass gives what the candidate-by-candidate rule gives: an id
+        admitted in this batch counts at least the estimate of every later
+        candidate, so it is never a minimum that a later one beats.  So the
+        j-th candidate over the minimum meets the j-th least resident of
+        those before the batch, and the first that does not beat it ends
+        the batch."""
+        ests = np.asarray(ests, _MASS_DTYPE)
+        if not ests.size:
+            return
+        n0, free = self.n, self.capacity - self.n
+        # the least count an eviction must beat; none before a resident
+        floor = self.counts[:n0].min() if n0 else np.inf
+        if free:  # in descending order, candidates take free slots first
+            order = np.argsort(ests, kind="stable")[::-1]
+            fill, order = order[:free], order[free:]
+            order = order[ests[order] > floor]
+        else:
+            order = np.flatnonzero(ests > floor)
+            order = order[np.argsort(ests[order], kind="stable")[::-1]]
+            fill = order[:0]
+        self.candidates += fill.size + order.size
+        if order.size:
+            victims = self._least(min(order.size, n0), floor)
+            beats = ests[order[: victims.size]] > self.counts[victims]
+            k = victims.size if beats.all() else int(beats.argmin())
+            victims, order = victims[:k], order[:k]
+        else:
+            victims = order
+        won, slots = order, victims
+        if fill.size:
+            won = np.concatenate([fill, order])
+            slots = np.concatenate([np.arange(n0, n0 + fill.size), victims])
+        if not won.size:
+            return
+        new_ids = np.asarray(ids)[won].astype(np.int64)
+        out_ids, out_cnt = self.ids[victims], self.counts[victims]
+        self.ids[slots], self.counts[slots] = new_ids, ests[won]
+        self.n += fill.size
+        self.admitted += won.size
+        self.evicted += victims.size
+        self._reindex(out_ids, new_ids, slots)
+        if victims.size:  # one vectorized sketch push for the whole batch
+            sketch.raise_to(out_ids, out_cnt)
+
+    def _reindex(self, out_ids: np.ndarray, in_ids: np.ndarray,
+                 in_slots: np.ndarray) -> None:
+        """Update the residency index in place: drop the evicted ids, add
+        the admitted ones with their slots (what ``_index`` would build)."""
+        if self._dirty:
+            return  # rebuilt from the slots on the next lookup
+        ids, slots = self._sorted_ids, self._sorted_slots
+        if out_ids.size:
+            keep = np.ones(ids.size, bool)
+            keep[np.searchsorted(ids, out_ids)] = False
+            ids, slots = ids[keep], slots[keep]
+        o = np.argsort(in_ids)
+        in_ids = in_ids[o]
+        at = np.searchsorted(ids, in_ids) + np.arange(o.size)
+        old = np.ones(ids.size + o.size, bool)
+        old[at] = False
+        self._sorted_ids = np.empty(old.size, np.int64)
+        self._sorted_ids[at], self._sorted_ids[old] = in_ids, ids
+        self._sorted_slots = np.empty(old.size, np.int64)
+        self._sorted_slots[at], self._sorted_slots[old] = in_slots[o], slots
 
     def head(self) -> tuple[np.ndarray, np.ndarray]:
         """(ids, counts) of resident entries, descending by count."""
@@ -254,6 +330,23 @@ class SpaceSaving:
         self._dirty = True
 
 
+def count_rows(ids: np.ndarray):
+    """Each row's distinct ids and how often each occurs, for an (F, B) id
+    array -> ``(uids, counts, bounds)``: row f's distinct ids, ascending,
+    are ``uids[bounds[f]:bounds[f + 1]]`` with float ``counts``, what
+    ``np.unique(ids[f], return_counts=True)`` gives.  One row-wise sort
+    counts every row: a row of B ids sorts in cache, which costs less than
+    one ``np.unique`` over all the rows or one per row."""
+    ids = np.array(ids, order="C")  # a copy, sorted in place
+    ids.sort(axis=1)
+    first = np.ones(ids.shape, bool)  # where each distinct id starts
+    np.not_equal(ids[:, 1:], ids[:, :-1], out=first[:, 1:])
+    at = np.flatnonzero(first)
+    counts = np.diff(at, append=ids.size).astype(_MASS_DTYPE)
+    bounds = np.searchsorted(at, np.arange(ids.shape[0] + 1) * ids.shape[1])
+    return ids.ravel()[at], counts, bounds
+
+
 class FeatureSketch:
     """One feature's complete streaming state: sketch + head + ring + mass.
 
@@ -277,7 +370,7 @@ class FeatureSketch:
 
     def _push_ring(self, raw_ids: np.ndarray) -> None:
         r = self.ring.shape[0]
-        ids = np.asarray(raw_ids, np.int64).reshape(-1)[-r:]
+        ids = np.asarray(raw_ids).reshape(-1)[-r:]
         pos = self.ring_pos % r
         k = min(ids.size, r - pos)
         self.ring[pos : pos + k] = ids[:k]
@@ -290,34 +383,35 @@ class FeatureSketch:
         (with-multiplicity) ids."""
         self._ingest(raw_ids, into_sketch=True)
 
-    def fold_ids(self, raw_ids: np.ndarray) -> None:
-        """Async path: the id-level head/ring bookkeeping from the host
-        batch copy, once the batch's device-computed cell delta is folded
-        (``cms.add_cells``; the sketch update never touched the host hot
-        path).  Resident ids' mass lands in the sketch too (their cells go
-        stale-HIGH, which the min/offer invariants tolerate); their exact
-        counters still get the increments."""
-        self._ingest(raw_ids, into_sketch=False)
-
     def _ingest(self, raw_ids: np.ndarray, *, into_sketch: bool) -> None:
-        """The id-level bookkeeping BOTH update paths share (so they
-        cannot drift apart — restart-exactness depends on sync and async
-        computing identical head/ring/mass state): resident head ids take
-        exact increments, absent ids go through SpaceSaving admission,
-        the ring and mass advance.  ``into_sketch`` adds the absent mass
-        to the CMS too (the async path already folded it as cells)."""
+        """``ingest_counted`` for one batch of this feature's raw ids,
+        counted here (the tracker counts all its features at once)."""
         raw_ids = np.asarray(raw_ids).reshape(-1)
         if raw_ids.size == 0:
             return
-        uids, ucnt = np.unique(raw_ids, return_counts=True)
-        ucnt = ucnt.astype(_MASS_DTYPE)
+        uids, ucnt, _ = count_rows(raw_ids[None])
+        self.ingest_counted(raw_ids, uids, ucnt, into_sketch=into_sketch)
+
+    def ingest_counted(self, raw_ids: np.ndarray, uids: np.ndarray,
+                       ucnt: np.ndarray, *, into_sketch: bool) -> None:
+        """The id-level bookkeeping EVERY update path shares (so they
+        cannot drift apart — restart-exactness depends on sync and async
+        computing identical head/ring/mass state), for a batch whose
+        distinct ids ``uids`` (ascending) occur ``ucnt`` times in
+        ``raw_ids``: resident head ids take exact increments, absent ids
+        go through SpaceSaving admission, the ring and mass advance.
+        ``into_sketch`` adds the absent mass to the CMS too.  The async
+        fold passes False: it already folded the batch's device-computed
+        cell delta (``cms.add_cells``), resident ids' mass included (their
+        cells go stale-HIGH, which the min/offer invariants tolerate)."""
         slots, resident = self.hh.split_resident(uids)
         self.hh.bump(slots[resident], ucnt[resident])
-        absent_ids, absent_cnt = uids[~resident], ucnt[~resident]
+        absent = ~resident
+        absent_ids = uids[absent]
         if into_sketch:
-            self.cms.add(absent_ids, absent_cnt)
+            self.cms.add(absent_ids, ucnt[absent])
         self.hh.offer(absent_ids, self.cms.estimate(absent_ids), self.cms)
-        self.mass += float(ucnt.sum())
+        self.mass += float(raw_ids.size)  # the sum of ucnt, exactly
         self._push_ring(raw_ids)
 
     def decay(self, gamma: float) -> None:

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.obs.trace import span
 from repro.stream.points import sample_from_counts
-from repro.stream.sketch import FeatureSketch
+from repro.stream.sketch import FeatureSketch, count_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +93,12 @@ class IdFrequencyTracker:
     @property
     def nbytes(self) -> int:
         return sum(c.nbytes for c in self.counts)
+
+
+def _head_counters(heads) -> dict[str, int]:
+    """SpaceSaving's running trace counters, summed over ``heads``."""
+    return {k: sum(getattr(h, k) for h in heads)
+            for k in ("admitted", "evicted", "candidates", "rebuilds")}
 
 
 class SketchFrequencyTracker:
@@ -164,8 +170,7 @@ class SketchFrequencyTracker:
             delta = self._cell_counter(jnp.asarray(cols, jnp.int32))
             self._folder.submit((delta, cols))  # device_get happens off-thread
         else:
-            for f in self.tracked:
-                self.features[f].observe(sparse[:, f])
+            self._fold_heads(sparse[:, list(self.tracked)], into_sketch=True)
         self.batches_seen += 1
         w = self.config.window
         if w and self.batches_seen % w == 0:
@@ -186,11 +191,22 @@ class SketchFrequencyTracker:
                     self.features[f].cms.add_cells(delta[j])
             with span("fold-heads") as s:
                 heads = [self.features[f].hh for f in self.tracked]
-                a0, e0 = sum(h.admitted for h in heads), sum(h.evicted for h in heads)
-                for j, f in enumerate(self.tracked):
-                    self.features[f].fold_ids(cols[:, j])
-                s.set_metadata(admitted=sum(h.admitted for h in heads) - a0,
-                               evicted=sum(h.evicted for h in heads) - e0)
+                before = _head_counters(heads)
+                self._fold_heads(cols)
+                s.set_metadata(**{k: v - before[k]
+                                  for k, v in _head_counters(heads).items()})
+
+    def _fold_heads(self, cols: np.ndarray, *, into_sketch: bool = False) -> None:
+        """Every tracked feature's head and ring update for one (B,
+        F_tracked) batch: one ``count_rows`` over all the features, then
+        each feature's ``ingest_counted``.  ``into_sketch`` adds the absent
+        ids' mass to the sketches too, on the path with no cell delta."""
+        uids, counts, bounds = count_rows(cols.T)
+        bounds = bounds.tolist()
+        for j, f in enumerate(self.tracked):
+            lo, hi = bounds[j], bounds[j + 1]
+            self.features[f].ingest_counted(cols[:, j], uids[lo:hi], counts[lo:hi],
+                                            into_sketch=into_sketch)
 
     def _close_window(self) -> None:
         """Window boundary: snapshot trigger statistics, then decay."""

@@ -138,6 +138,22 @@ def test_spacesaving_head_is_exact_on_zipf_top():
     assert (fs.estimate(probe) >= true[probe] - 1e-9).all()
 
 
+@pytest.mark.parametrize("shape", [(1, 0), (1, 1), (1, 300), (5, 64), (17, 2048)])
+def test_count_rows_matches_unique_per_row(shape):
+    """One row-wise count gives each row what ``np.unique`` gives it."""
+    from repro.stream.sketch import count_rows
+
+    rng = np.random.default_rng(sum(shape))
+    ids = (rng.zipf(1.2, shape) % 1000).astype(np.int32)
+    uids, counts, bounds = count_rows(np.asfortranarray(ids))  # as the fold's transpose
+    assert bounds.shape == (shape[0] + 1,) and counts.dtype == np.float64
+    for f in range(shape[0]):
+        u, c = np.unique(ids[f], return_counts=True)
+        np.testing.assert_array_equal(uids[bounds[f]:bounds[f + 1]], u)
+        np.testing.assert_array_equal(counts[bounds[f]:bounds[f + 1]], c)
+    assert bounds[-1] == uids.size
+
+
 def test_spacesaving_counts_admissions_and_evictions():
     """The running counters the fold's ``fold-heads`` span reports."""
     from repro.stream import SpaceSaving
@@ -151,6 +167,215 @@ def test_spacesaving_counts_admissions_and_evictions():
     hh.offer(np.array([8, 9]), np.array([4.0, 2.5]), cms)
     assert (hh.admitted, hh.evicted) == (3, 1)
     assert sorted(hh.head()[0].tolist()) == [5, 8]
+    assert (hh.candidates, hh.rebuilds) == (4, 0)
+
+
+def _offer_oracle(hh, ids, ests, sketch):
+    """SpaceSaving admission candidate by candidate, as ``offer`` did it
+    before the one-pass rule: each candidate in descending estimate order
+    takes a free slot, else evicts ``argmin`` of the counts if its
+    estimate beats it, else ends the batch; any admission leaves the
+    residency index to be rebuilt."""
+    order = np.argsort(np.asarray(ests), kind="stable")[::-1]
+    n0 = hh.n
+    evicted_ids, evicted_cnt = [], []
+    for j in order.tolist():
+        i, est = int(ids[j]), float(ests[j])
+        if hh.n < hh.capacity:
+            hh.ids[hh.n], hh.counts[hh.n] = i, est
+            hh.n += 1
+            continue
+        s = int(np.argmin(hh.counts))
+        if est <= hh.counts[s]:
+            break
+        evicted_ids.append(int(hh.ids[s]))
+        evicted_cnt.append(float(hh.counts[s]))
+        hh.ids[s], hh.counts[s] = i, est
+    hh.admitted += hh.n - n0 + len(evicted_ids)
+    hh.evicted += len(evicted_ids)
+    hh._dirty |= hh.n > n0 or bool(evicted_ids)
+    if evicted_ids:
+        sketch.raise_to(np.asarray(evicted_ids), np.asarray(evicted_cnt))
+
+
+def _assert_index_fresh(hh):
+    """The residency index equals a rebuild from the slots."""
+    order = np.argsort(hh.ids[: hh.n], kind="stable")
+    np.testing.assert_array_equal(hh._sorted_ids, hh.ids[: hh.n][order])
+    np.testing.assert_array_equal(hh._sorted_slots, order)
+
+
+def _assert_same_head(a, b):
+    assert (a.n, a.admitted, a.evicted) == (b.n, b.admitted, b.evicted)
+    np.testing.assert_array_equal(a.ids, b.ids)
+    assert a.counts.tobytes() == b.counts.tobytes()
+
+
+def _offer_case(name, rng):
+    """-> (capacity, resident counts, batches of estimates) for one case;
+    residents are ids 0.., candidates fresh ids above them."""
+    def decayed(size, hi):  # integer counts after one decay: many ties
+        return rng.integers(1, hi, size) * 0.95
+
+    if name == "fill":
+        return 8, [], [[3.0, 1.0, 2.0], [5.0, 4.0]]
+    if name == "fill_then_evict":
+        return 8, [3.0, 1.0, 4.0, 1.0, 5.0], [[2.0, 6.0, 0.5, 7.0, 1.0, 9.0, 3.5, 0.9]]
+    if name == "none_beats_min":
+        return 6, [5.0, 7.0, 6.0, 5.0, 9.0, 8.0], [[5.0, 4.0, 1.0, 5.0], []]
+    if name == "all_admit":
+        return 6, [1.0, 2.0, 3.0, 4.0, 5.0, 6.0], [[100.0, 50.0, 70.0]]
+    if name == "more_than_capacity":
+        return 8, [], [rng.integers(1, 50, 20) * 1.0, rng.integers(40, 90, 20) * 1.0]
+    if name == "ties":
+        return 64, decayed(64, 4), [decayed(40, 5) for _ in range(6)] + [np.full(30, 1.9)]
+    if name == "empty":
+        return 4, [2.0, 1.0], [[]]
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", ["fill", "fill_then_evict", "none_beats_min",
+                                  "all_admit", "more_than_capacity", "ties", "empty"])
+def test_one_pass_offer_matches_sequential_admission(case):
+    """``SpaceSaving.offer`` admits in one pass exactly what the
+    candidate-by-candidate rule admits: the same ids in the same slots
+    with the same counts, the same admissions and evictions, the same
+    sketch after the evicted counts are pushed back; and its in-place
+    residency index equals a rebuild."""
+    import copy
+
+    from repro.stream import SpaceSaving
+
+    rng = np.random.default_rng(7)
+    cap, resident, batches = _offer_case(case, rng)
+    hh = SpaceSaving(cap)
+    ids = np.full(cap, -1, np.int64)
+    ids[: len(resident)] = np.arange(len(resident))
+    counts = np.zeros(cap)
+    counts[: len(resident)] = resident
+    hh.load_state_tree([ids, counts])
+    hh.split_resident(np.zeros(0, np.int64))  # the lookup before each offer
+    cms = CountMinSketch(width=1 << 6, depth=3, seed=1)
+    cms.counters[:] = rng.integers(0, 6, cms.counters.shape) * 0.95
+    ref, ref_cms = copy.deepcopy(hh), copy.deepcopy(cms)
+    next_id = 1000
+    for ests in batches:
+        ests = np.asarray(ests, np.float64)
+        cand = np.arange(next_id, next_id + ests.size)
+        next_id += ests.size
+        # offered a slot: the free slots' worth, then those over the minimum
+        free = cap - ref.n
+        floor = ref.counts[: ref.n].min() if ref.n else np.inf
+        offered = hh.candidates + min(free, ests.size) + int(
+            (np.sort(ests)[::-1][free:] > floor).sum())
+        hh.offer(cand, ests, cms)
+        assert hh.candidates == offered
+        _offer_oracle(ref, cand, ests, ref_cms)
+        _assert_same_head(hh, ref)
+        assert cms.counters.tobytes() == ref_cms.counters.tobytes()
+        _assert_index_fresh(hh)
+    assert hh.rebuilds == 1  # the load's
+    assert hh.candidates >= hh.admitted
+
+
+def _host_delta(trk, sparse):
+    """The (F_tracked, depth, width) cell delta the in-step counter
+    computes, counted on the host."""
+    sk = [trk.features[f].cms for f in trk.tracked]
+    out = np.zeros((len(sk), sk[0].depth, sk[0].width), np.int32)
+    for j, (f, cms) in enumerate(zip(trk.tracked, sk)):
+        for r, cells in enumerate(cms.cells(sparse[:, f])):
+            out[j, r] = np.bincount(cells, minlength=cms.width)
+    return out
+
+
+def _ingest_before(fs, raw_ids, into_sketch):
+    """``FeatureSketch._ingest`` as it was before the batched fold: its own
+    ``np.unique``, residency from a fresh sort of the slots, and the
+    candidate-by-candidate admission."""
+    raw_ids = np.asarray(raw_ids).reshape(-1)
+    uids, ucnt = np.unique(raw_ids, return_counts=True)
+    ucnt = ucnt.astype(np.float64)
+    hh = fs.hh
+    order = np.argsort(hh.ids[: hh.n], kind="stable")
+    srt = np.append(hh.ids[: hh.n][order], -1)
+    pos = np.minimum(np.searchsorted(srt[:-1], uids), max(hh.n - 1, 0))
+    resident = srt[pos] == uids
+    hh.counts[order[pos[resident]]] += ucnt[resident]
+    absent_ids, absent_cnt = uids[~resident], ucnt[~resident]
+    if into_sketch:
+        fs.cms.add(absent_ids, absent_cnt)
+    _offer_oracle(hh, absent_ids, fs.cms.estimate(absent_ids), fs.cms)
+    fs.mass += float(ucnt.sum())
+    fs._push_ring(raw_ids)
+
+
+@pytest.mark.parametrize("path,seed", [("fold", 0), ("fold", 1), ("observe", 2)])
+def test_batched_fold_matches_per_feature_ingest(path, seed):
+    """Over a Zipf stream with window decays, on features whose heads
+    never fill, churn, and churn hard: the tracker's fold (one count of
+    every feature's ids, one-pass admission, in-place index) and its
+    synchronous path leave the state, bit for bit, and the head order
+    that the per-feature code with the sequential rule leaves.  The
+    in-place index equals a rebuild after every batch, and nothing
+    rebuilds it."""
+    vocab = (40, 3000, 500_000)
+    scfg = StreamConfig(width=1 << 10, depth=3, heavy=64, ring=256,
+                        decay=0.95, window=16)
+    new = SketchFrequencyTracker(vocab, scfg)
+    old = SketchFrequencyTracker(vocab, scfg)
+    old._fold_heads = lambda cols, into_sketch=False: [
+        _ingest_before(old.features[f], cols[:, j], into_sketch)
+        for j, f in enumerate(old.tracked)]
+    rng = np.random.default_rng(seed)
+    for _ in range(320):
+        sparse = np.stack([rng.zipf(1.2, 256) % v for v in vocab], 1).astype(np.int32)
+        kw = {"delta": _host_delta(new, sparse)} if path == "fold" else {}
+        new.observe({"sparse": sparse}, **kw)
+        old.observe({"sparse": sparse}, **kw)
+        for f in new.tracked:
+            _assert_index_fresh(new.features[f].hh)
+            assert new.features[f].hh.rebuilds == 0
+        for a, b in zip(new.state_tree(), old.state_tree()):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    for f in new.tracked:
+        for a, b in zip(new.features[f].hh.head(), old.features[f].hh.head()):
+            np.testing.assert_array_equal(a, b)
+    n = [new.features[f].hh.n for f in new.tracked]
+    assert n[0] == 40 and n[1] == n[2] == 64  # one head never filled
+    assert min(new.features[f].hh.evicted for f in new.tracked[1:]) > 100
+
+
+@pytest.mark.parametrize("async_fold", [False, True])
+def test_fold_updates_heads_only_through_their_methods(async_fold):
+    """Heads whose ``bump`` and ``offer`` are replaced on the instance (how
+    the benchmark plants a head that is never updated) stay as they were
+    through the fold, while the rings and the mass still advance."""
+    scfg = StreamConfig(width=1 << 9, depth=3, heavy=16, ring=128,
+                        async_fold=async_fold)
+    trk = SketchFrequencyTracker((100, 5000), scfg, tracked=(0, 1))
+    rng = np.random.default_rng(3)
+
+    def feed(n):
+        for _ in range(n):
+            sparse = np.stack([rng.zipf(1.3, 64) % 100, rng.zipf(1.3, 64) % 5000],
+                              1).astype(np.int32)
+            trk.observe({"sparse": sparse}, delta=_host_delta(trk, sparse))
+        trk.flush()
+
+    feed(3)
+    heads = [trk.features[f].hh for f in trk.tracked]
+    before = [(h.ids.copy(), h.counts.copy(), h.n) for h in heads]
+    mass = [trk.features[f].mass for f in trk.tracked]
+    for h in heads:
+        h.bump = lambda slots, counts: None
+        h.offer = lambda ids, ests, sketch: None
+    feed(3)
+    for h, (ids, counts, n) in zip(heads, before):
+        assert h.n == n
+        np.testing.assert_array_equal(h.ids, ids)
+        np.testing.assert_array_equal(h.counts, counts)
+    assert [trk.features[f].mass for f in trk.tracked] == [m + 3 * 64 for m in mass]
 
 
 def test_decay_scales_and_recency_wins():

@@ -112,8 +112,11 @@ def test_fold_phases_nest_in_fold_batch_on_their_own_thread(trained):
         assert [s.name for s in inner] == list(FOLD_PHASES)
         assert all(x.end <= y.start for x, y in zip(inner, inner[1:]))
     heads = _named(lines, "fold-heads")
-    assert all(set(s.stats) == {"admitted", "evicted"} for s in heads)
+    assert all(set(s.stats) == {"candidates", "admitted", "evicted", "rebuilds"}
+               for s in heads)
     assert sum(s.stats["admitted"] for s in heads) > 0
+    assert all(s.stats["candidates"] >= s.stats["admitted"] for s in heads)
+    assert sum(s.stats["rebuilds"] for s in heads) == 0  # updated in place
 
 
 def test_one_window_close_per_closed_window(trained):
@@ -185,6 +188,6 @@ def test_enqueue_wait_spans_count_blocked_submits(tmp_path):
         d = np.asarray(d)
         for j, f in enumerate(ref.tracked):
             ref.features[f].cms.add_cells(d[j])
-            ref.features[f].fold_ids(b[:, f])
+            ref.features[f]._ingest(b[:, f], into_sketch=False)
     for a, r in zip(trk.state_tree()[1:], ref.state_tree()[1:]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
