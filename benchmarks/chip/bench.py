@@ -62,7 +62,8 @@ def load_spec(workload: str, overrides: dict | None = None) -> dict:
     if workload not in cells and "cell" not in overrides:
         raise SystemExit(f"bench: no workload {workload!r} in BENCHMARK.json")
     cell = cells.get(workload) or overrides["cell"]
-    with open(HERE / "configs" / f"{cell['config']}.json") as f:
+    config_dir = Path(overrides.get("config_dir", HERE / "configs"))
+    with open(config_dir / f"{cell['config']}.json") as f:
         cfg = json.load(f)
     mix = traffic.load_mix(cell["traffic"])
     cfg.update(overrides.get("config", {}))
@@ -75,7 +76,8 @@ def load_spec(workload: str, overrides: dict | None = None) -> dict:
     e2e_names = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
              if (workload in m["workloads"] if "workloads" in m else m["moves"] in e2e_names)]
-    return {"cell": cell, "cfg": cfg, "mix": mix, "end_to_end": e2e, "per_layer": layer}
+    return {"cell": cell, "cfg": cfg, "mix": mix, "end_to_end": e2e, "per_layer": layer,
+            "reference": config_dir / f"{cfg['reference']}.py"}
 
 
 def load_module(path: Path):
@@ -122,7 +124,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     cfg, mix = spec["cfg"], spec["mix"]
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
     dev = device_info()
-    ref = load_module(HERE / "configs" / f"{cfg['reference']}.py")
+    ref = load_module(spec["reference"])
     runner = load_module(HERE / "harness" / f"{mix['mode']}.py")
     out = runner.run(spec["cell"], cfg, mix, ref, seed, seconds, trace,
                      T_START if t_start is None else t_start,

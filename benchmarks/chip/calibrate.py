@@ -48,12 +48,12 @@ def main(argv=None) -> int:
 
     cfg, mix = spec["cfg"], spec["mix"]
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
-    ref = bench.load_module(bench.HERE / "configs" / f"{cfg['reference']}.py")
+    ref = bench.load_module(spec["reference"])
     if mix["mode"] == "train":
         from harness import faults
 
         names = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_flatten_with_path(
-            jax.eval_shape(W.make_fn(cfg)[0], W.seed_key(0)))[0]]
+            jax.eval_shape(W.make_fn(cfg, ref)[0], W.seed_key(0)))[0]]
 
         def half(batch):
             return {k: v[: len(v) // 2] for k, v in batch.items()}
@@ -94,7 +94,7 @@ def main(argv=None) -> int:
     for seed in args.seeds:
         t0 = time.perf_counter()
         if st is None:
-            st = serve.Setup(cfg, mix, seed)
+            st = serve.Setup(cfg, mix, ref, seed)
         else:  # the next seed's weights and samples in the same engine
             from harness import traffic
 

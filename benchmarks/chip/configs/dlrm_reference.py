@@ -2,8 +2,9 @@
 tables: the reference that decides ``correct`` for the ``dlrm_*``
 configurations.  Straightforward ``jax.numpy``: gathers, an einsum and
 dense layers, no kernel, cache, padding or batching trick.  It reads the
-benchmark's per-feature weights (``harness/weights.py``) and imports
-nothing of the program.
+benchmark's per-feature weights (``harness/weights.py``), makes the dense
+ones itself (``make_dense``), trains with plain SGD (``init_opt``,
+``train_step``) and imports nothing of the program.
 
 CCE (the paper's Algorithm 3): ``emb(id) = concat_i(M_i[ptr_i[id]] +
 M'_i[h'_i(id)])`` with ``h'_i`` a multiply-shift hash over the ``k`` helper
@@ -15,6 +16,8 @@ exact, window-decayed count of every id a stream fed (``fed_counts``), what
 a SpaceSaving head of the stream's heaviest ids must show.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +82,26 @@ def _mlp(layers, x, relu_last: bool, precision: str):
     return x
 
 
+def _init_mlp(key, sizes):
+    layers = []
+    for i, (a, b) in enumerate(zip(sizes[:-1], sizes[1:])):
+        w = jax.random.normal(jax.random.fold_in(key, i), (a, b), jnp.float32)
+        layers.append({"w": w / math.sqrt(a), "b": jnp.zeros((b,), jnp.float32)})
+    return layers
+
+
+def make_dense(cfg: dict, key):
+    """The bottom and top MLPs, from the first two of the three keys split
+    from ``key`` (the embedding tables take the third): weights normal over
+    the square root of fan-in, biases zero.  The top MLP reads the bottom's
+    output beside the upper triangle of the pairwise interaction."""
+    kb, kt, _ = jax.random.split(key, 3)
+    n = len(cfg["vocab_sizes"])
+    bottom = (cfg["n_dense"], *cfg["bottom_mlp"])
+    top = (cfg["bottom_mlp"][-1] + (n + 1) * n // 2, *cfg["top_mlp"])
+    return {"bottom": _init_mlp(kb, bottom), "top": _init_mlp(kt, top)}
+
+
 def logits(params, emb_buffers, dense, sparse, precision: str = "highest"):
     x0 = _mlp(params["bottom"], dense, True, precision)  # (B, d2)
     v = jnp.concatenate([x0[:, None, :], embed(params["emb"], emb_buffers, sparse)], axis=1)
@@ -94,14 +117,29 @@ def bce(params, emb_buffers, batch, precision: str = "highest"):
     return jnp.mean(jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z))))
 
 
-def sgd_step(params, emb_buffers, batch, lr: float, clip_norm: float,
-             precision: str = "highest"):
+def clip(grads, clip_norm: float):
+    """The gradient scaled down to a global norm of at most ``clip_norm``."""
+    norm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(grads)))
+    return jax.tree.map(
+        lambda x: x * jnp.minimum(1.0, clip_norm / jnp.maximum(norm, 1e-12)), grads)
+
+
+def init_opt(params, cfg: dict):
+    """The optimizer's state: plain SGD keeps none, and this reference
+    trains nothing else."""
+    opt = cfg["optimizer"]
+    if opt["name"] != "sgd" or opt.get("momentum", 0.0) != 0.0:
+        raise ValueError(f"dlrm_reference trains plain SGD, not {opt}")
+    return {}
+
+
+def train_step(params, opt_state, emb_buffers, batch, cfg: dict, precision: str = "highest"):
     """One SGD step with the gradient clipped to a global norm:
-    (new params, loss)."""
+    (new params, new optimizer state, loss)."""
+    opt = cfg["optimizer"]
     loss, g = jax.value_and_grad(bce)(params, emb_buffers, batch, precision)
-    norm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
-    g = jax.tree.map(lambda x: x * jnp.minimum(1.0, clip_norm / jnp.maximum(norm, 1e-12)), g)
-    return jax.tree.map(lambda p, x: p - lr * x, params, g), loss
+    g = clip(g, opt["clip_norm"])
+    return jax.tree.map(lambda p, x: p - opt["lr"] * x, params, g), opt_state, loss
 
 
 def sketch_coeffs(stream_seed: int, feature: int, depth: int):
@@ -114,10 +152,11 @@ def sketch_coeffs(stream_seed: int, feature: int, depth: int):
     return a, b
 
 
-def sketch_delta(sparse, features, stream: dict) -> np.ndarray:
+def sketch_delta(sparse, features, cfg: dict) -> np.ndarray:
     """(len(features), depth, width) int64: every id of the batch counted
     once into each hash row's cell ``(a * id + b mod 2**32) >> (32 -
-    log2 width)``."""
+    log2 width)``, with ``cfg["stream"]``'s width, depth and seed."""
+    stream = cfg["stream"]
     width, depth = int(stream["width"]), int(stream["depth"])
     shift = np.uint32(32 - (width.bit_length() - 1))
     sparse = np.asarray(sparse)
@@ -130,11 +169,12 @@ def sketch_delta(sparse, features, stream: dict) -> np.ndarray:
     return out
 
 
-def fed_counts(pool, n_fed: int, features, stream: dict) -> dict:
+def fed_counts(pool, n_fed: int, features, cfg: dict) -> dict:
     """Exact counts of the ids fed by ``n_fed`` batches cycled from
     ``pool``, each batch's count decayed by ``decay`` once for every window
     of ``window`` batches that closed after it: {feature: (ids, counts)},
-    heaviest first."""
+    heaviest first; ``window`` and ``decay`` are ``cfg["stream"]``'s."""
+    stream = cfg["stream"]
     window, decay = int(stream.get("window", 0)), float(stream.get("decay", 1.0))
     t = np.arange(n_fed)
     closes = n_fed // window - t // window if window else np.zeros(n_fed, np.int64)

@@ -55,13 +55,13 @@ def _warm(engine, trk, sampler, n_dense, rng, max_batch, warm_samples):
 class Setup:
     """The engine with its weights, warmed up, and the served samples."""
 
-    def __init__(self, cfg, mix, seed: int, engine_hook=None):
+    def __init__(self, cfg, mix, ref, seed: int, engine_hook=None):
         from repro.serve.dlrm import DLRMServeEngine
 
         self.cfg, self.mix, self.seed = cfg, mix, seed
         pcfg = program.dlrm_config(cfg)
         program.check_tables(pcfg, W.table_shapes(cfg))
-        self.make = W.make_fn(cfg)
+        self.make = W.make_fn(cfg, ref)
         self.key = W.seed_key(seed)
         params, bufs = self.make[0](self.key), self.make[1](self.key)
         prog_params, prog_bufs = program.to_program(pcfg, params, bufs)
@@ -205,7 +205,7 @@ def check(st: Setup, ref, out: dict, precision: str, control: str | None) -> dic
 
 def run(cell, cfg, mix, ref, seed: int, seconds: float, trace: bool, t_start: float,
         *, control: str | None = None, engine_hook=None, drain_s: float = DRAIN_S):
-    st = Setup(cfg, mix, seed, engine_hook)
+    st = Setup(cfg, mix, ref, seed, engine_hook)
     out = window(st, mix, seconds, seed, trace, drain_s)
     out["setup_s"] = out["t_setup"] - t_start
     st.close()

@@ -14,7 +14,9 @@ window with a host span (``WINDOW_SPAN``).  The reduction reads the
   program), or to ``(no span)``.
 
 Host and device events share the trace's clock: the profiler puts both on
-the host's time line.
+the host's time line.  Each host event keeps its thread and its args (a
+span's keyword arguments), for readers that count what a span records; the
+reduction reads neither.
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ import glob
 import os
 import shutil
 import tempfile
+import warnings
 
 WINDOW_SPAN = "bench-window"
 OPS_LINE = "XLA Ops"
@@ -41,16 +44,33 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    #: a host event's thread: the index of its line among the host lines
+    #: (one line a thread; threads' names repeat, so the name is no key)
+    thread: int | None = None
+    #: the profiler's own host event, whose stats ``args`` reads on demand
+    raw: object = dataclasses.field(default=None, repr=False, compare=False)
 
     @property
     def end_ns(self) -> float:
         return self.start_ns + self.dur_ns
 
+    @property
+    def args(self) -> dict | None:
+        """A host event's args (the span's keyword arguments, or what it set
+        through ``set_metadata``), by name; None where it has none.  Read
+        when asked for: a traced run holds far more events than any reader
+        asks the args of."""
+        if self.raw is None:
+            return None
+        with warnings.catch_warnings():  # each read of ``stats`` warns
+            warnings.simplefilter("ignore", DeprecationWarning)
+            return dict(self.raw.stats) or None
+
 
 @dataclasses.dataclass
 class Trace:
     """The events a reduction needs: per device, its ops and programs, and
-    the host spans."""
+    the host spans with their threads and args."""
 
     ops: dict[str, list[Event]]
     modules: dict[str, list[Event]]
@@ -66,6 +86,7 @@ def load(profile_data) -> Trace:
     ops: dict[str, list[Event]] = {}
     modules: dict[str, list[Event]] = {}
     host: list[Event] = []
+    n_threads = 0
     for plane in profile_data.planes:
         if _is_device_plane(plane.name):
             for line in plane.lines:
@@ -76,7 +97,10 @@ def load(profile_data) -> Trace:
                     plane.name, []).extend(evs)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host.extend(Event(e.name, e.start_ns, e.duration_ns) for e in line.events)
+                thread = n_threads
+                n_threads += 1
+                host.extend(Event(e.name, e.start_ns, e.duration_ns, thread, e)
+                            for e in line.events)
     return Trace(ops, modules, host)
 
 

@@ -53,10 +53,10 @@ def tracker_numbers(ref, cfg, pool, tracked, deltas, heads) -> dict:
     share missing from every reading, the count gap from the last alone
     (after three steps a count of a few ids is off by a whole id whenever
     the sketch's estimate that admitted it collided in every row)."""
-    stream, top = cfg["stream"], int(cfg["head_check_top"])
-    want = [ref.sketch_delta(pool[i]["sparse"], tracked, stream) for i in range(len(deltas))]
+    top = int(cfg["head_check_top"])
+    want = [ref.sketch_delta(pool[i]["sparse"], tracked, cfg) for i in range(len(deltas))]
     out = {"sketch_delta_gap": compare.sketch_delta_gap(deltas, want)}
-    read = {n: compare.head_numbers(h, ref.fed_counts(pool, n, tracked, stream), top)
+    read = {n: compare.head_numbers(h, ref.fed_counts(pool, n, tracked, cfg), top)
             for n, h in heads.items()}
     out["head_miss_share"] = max(r["head_miss_share"] for r in read.values())
     out["head_count_gap"] = read[max(read)]["head_count_gap"]
@@ -74,19 +74,18 @@ def _readings(p0, after1, after3, losses, lr):
 
 def reference_readings(ref, make, key, pool, cfg, precision: str, *, batch_fn=None):
     """The plain reference's first three steps from the same weights and
-    batches, at ``precision``; read as the program's are read."""
-    opt = cfg["optimizer"]
+    batches, at ``precision``, through its optimizer with the state that
+    ``ref.init_opt`` gives; read as the program's are read."""
     p0, bufs = make[0](key), make[1](key)
-    step = jax.jit(lambda p, b, x: ref.sgd_step(p, b, x, opt["lr"], opt["clip_norm"],
-                                                 precision))
-    params, losses, after1 = p0, [], None
+    step = jax.jit(lambda p, s, b, x: ref.train_step(p, s, b, x, cfg, precision))
+    params, state, losses, after1 = p0, ref.init_opt(p0, cfg), [], None
     for i in range(N_CHECKED):
         batch = pool[i] if batch_fn is None else batch_fn(pool[i])
-        params, loss = step(params, bufs, batch)
+        params, state, loss = step(params, state, bufs, batch)
         losses.append(loss)
         if i == 0:
             after1 = params
-    return _readings(p0, after1, params, losses, opt["lr"])
+    return _readings(p0, after1, params, losses, cfg["optimizer"]["lr"])
 
 
 def run(cell, cfg, mix, ref, seed: int, seconds: float, trace: bool, t_start: float,
@@ -97,7 +96,7 @@ def run(cell, cfg, mix, ref, seed: int, seconds: float, trace: bool, t_start: fl
     pcfg = program.dlrm_config(cfg)
     shapes = W.table_shapes(cfg)
     program.check_tables(pcfg, shapes)
-    make = W.make_fn(cfg)
+    make = W.make_fn(cfg, ref)
     key = W.seed_key(seed)
     params, bufs = make[0](key), make[1](key)
     prog_params, prog_bufs = program.to_program(pcfg, params, bufs)
@@ -111,7 +110,8 @@ def run(cell, cfg, mix, ref, seed: int, seconds: float, trace: bool, t_start: fl
         step = step_hook(step)
     state = init_state(prog_params, optimizer, dyn)
     del prog_params, prog_bufs, dyn
-    pool = traffic.train_pool(mix, cfg["vocab_sizes"], cfg["n_dense"], batch, seed)
+    pool = traffic.train_pool(mix, cfg["vocab_sizes"], cfg["n_dense"], batch, seed,
+                              bag_sizes=cfg.get("bag_sizes"))
     trainer = Trainer(step, state, static, itertools.cycle(pool), id_tracker=trk,
                       seed=seed % (1 << 31))
     del state

@@ -7,6 +7,8 @@ full table; a larger one is a CCE table with ``c`` columns of a main and a
 helper codebook of ``k`` rows each (the paper's rule, Sec. 4.1).  CCE
 pointers are uniform over the codebook rows, as after a clustering; the
 helper hash coefficients are random 32-bit pairs with an odd multiplier.
+The embedding tables take the third of the three keys split from the
+seed's; a reference's dense parameters may take the other two.
 """
 from __future__ import annotations
 
@@ -41,25 +43,17 @@ def table_shapes(cfg: dict) -> tuple[TableShape, ...]:
     return tuple(out)
 
 
-def _mlp(key, sizes):
-    layers = []
-    for i, (a, b) in enumerate(zip(sizes[:-1], sizes[1:])):
-        w = jax.random.normal(jax.random.fold_in(key, i), (a, b), jnp.float32)
-        layers.append({"w": w / math.sqrt(a), "b": jnp.zeros((b,), jnp.float32)})
-    return layers
-
-
-def make_fn(cfg: dict):
-    """Two jitted functions of the key: ``params`` (``bottom``, ``top`` and
-    a per-feature ``emb`` list) and ``buffers`` (one dict per feature).
-    Each is one program, so every caller of one gets the same bits."""
+def make_fn(cfg: dict, ref):
+    """Two jitted functions of the key: ``params`` (a per-feature ``emb``
+    list beside the dense parameters that the configuration's reference
+    module makes, ``ref.make_dense(cfg, key)``, when it defines it) and
+    ``buffers`` (one dict per feature).  Each is one program, so every
+    caller of one gets the same bits."""
     shapes = table_shapes(cfg)
-    n_pairs = (len(shapes) + 1) * len(shapes) // 2
-    bottom = (cfg["n_dense"], *cfg["bottom_mlp"])
-    top = (cfg["bottom_mlp"][-1] + n_pairs, *cfg["top_mlp"])
+    make_dense = getattr(ref, "make_dense", None)
 
     def make(key):
-        kb, kt, ke = jax.random.split(key, 3)
+        ke = jax.random.split(key, 3)[2]
         emb, bufs = [], []
         for f, s in enumerate(shapes):
             kf = jax.random.fold_in(ke, f)
@@ -77,8 +71,8 @@ def make_fn(cfg: dict):
                 "hs": hs.at[:, 0].set(hs[:, 0] | jnp.uint32(1)),
                 "epoch": jnp.zeros((), jnp.int32),
             })
-        params = {"bottom": _mlp(kb, bottom), "emb": emb, "top": _mlp(kt, top)}
-        return params, bufs
+        dense = make_dense(cfg, key) if make_dense is not None else {}
+        return {**dense, "emb": emb}, bufs
 
     return jax.jit(lambda k: make(k)[0]), jax.jit(lambda k: make(k)[1])
 

@@ -56,18 +56,23 @@ def _table_bytes(s, itemsize: int) -> int:
     return (2 * s.c * s.k * (s.d2 // s.c) if s.kind == "cce" else s.d1 * s.d2) * itemsize
 
 
-def lookup_work(shapes, batch: int, *, backward: bool, itemsize: int = 4):
+def lookup_work(shapes, batch: int, *, backward: bool, itemsize: int = 4,
+                bag_sizes=None):
     """(adds, bytes) that a batch's embedding lookup must do, whatever its
     algorithm: read every row index and every table once, write the
     output once, and sum each output element's rows.  The backward reads
     the indices and the output gradient once, writes the table gradient
     once, and adds each gradient row into its table rows.  (A one-hot
-    matmul does far more; this is the work it is measured against.)"""
+    matmul does far more; this is the work it is measured against.)
+    With ``bag_sizes`` (one id a feature by default) each example looks
+    up a bag of ids a feature and pools them into one output row: index
+    bytes and adds count per id, output bytes per bag."""
     adds = nbytes = 0
-    for s in shapes:
+    for f, s in enumerate(shapes):
         idx, out, a = _per_id(s, itemsize)
-        adds += batch * a
-        nbytes += batch * (idx + out) + _table_bytes(s, itemsize)
+        n_ids = batch * (1 if bag_sizes is None else int(bag_sizes[f]))
+        adds += n_ids * a
+        nbytes += n_ids * idx + batch * out + _table_bytes(s, itemsize)
     return adds, nbytes
 
 

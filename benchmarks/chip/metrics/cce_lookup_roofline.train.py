@@ -16,6 +16,7 @@ def read(ctx):
     batch = r["examples"] // r["steps"]
     best = 0.0
     for backward in (False, True):
-        adds, nbytes = work.lookup_work(shapes, batch, backward=backward)
+        adds, nbytes = work.lookup_work(shapes, batch, backward=backward,
+                                        bag_sizes=ctx["cfg"].get("bag_sizes"))
         best += work.roofline_s(adds, nbytes, peak)
     return 100.0 * best * r["steps"] / kernel_s

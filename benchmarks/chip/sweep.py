@@ -38,7 +38,8 @@ def main(argv=None) -> int:
 
     cfg, mix = spec["cfg"], spec["mix"]
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
-    st = serve.Setup(cfg, mix, args.seed)
+    ref = bench.load_module(spec["reference"])
+    st = serve.Setup(cfg, mix, ref, args.seed)
     for i, rate in enumerate(sorted(args.rates)):
         m = dict(mix, rate_qps=rate)
         out = serve.window(st, m, args.seconds, args.seed + i, False)
