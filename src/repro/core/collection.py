@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 from typing import Any, Sequence
 
@@ -102,6 +103,9 @@ class TableGroup:
     #: k_multiple=1 and a k_multiple=M layout are bit-interconvertible
     #: (``grouped_layout_migration``).
     k_multiple: int = 1
+    #: universal groups only: each member's bag length (ids summed into
+    #: one embedding per example; 1 = one id)
+    bags: tuple[int, ...] = ()
 
     @functools.cached_property
     def col_counts(self) -> tuple[int, ...]:
@@ -119,6 +123,19 @@ class TableGroup:
     def k_pad(self) -> int:
         k = max(t.fuse_spec.k for t in self.tables)
         return -(-k // self.k_multiple) * self.k_multiple
+
+    @functools.cached_property
+    def runs(self) -> tuple[tuple[int, int], ...]:
+        """(columns, bag length) of each run of adjacent supertable
+        columns whose features share a bag length, in column order — one
+        kernel launch each (``kops.cce_lookup``)."""
+        out: list[list[int]] = []
+        for n, bag in zip(self.col_counts, self.bags):
+            if out and out[-1][1] == bag:
+                out[-1][0] += n
+            else:
+                out.append([n, bag])
+        return tuple((n, bag) for n, bag in out)
 
 
 # --- universal-slab plumbing (shared by device + host paths) ----------------
@@ -193,12 +210,17 @@ def _gcd_all(vals) -> int:
 class EmbeddingCollection:
     tables: tuple[Any, ...]
     groups: tuple[TableGroup, ...]
+    #: ids a feature looks up and sum-pools per example (1 = one id).
+    #: The sparse input is (B, sum(bag_sizes)), feature f's bag in the
+    #: columns from sum(bag_sizes[:f]).
+    bag_sizes: tuple[int, ...]
 
     # --- construction ----------------------------------------------------
 
     @classmethod
     def build(cls, tables: Sequence[Any], mode: str = "univ",
-              k_multiple: int = 1) -> "EmbeddingCollection":
+              k_multiple: int = 1,
+              bag_sizes: Sequence[int] = ()) -> "EmbeddingCollection":
         """``mode``:
         * "univ" (default) — universal fusion: every gather-sum table
           (``fuse_spec``) joins one supertable per dtype; ONE launch for
@@ -213,13 +235,20 @@ class EmbeddingCollection:
         configs set it to the shard count; layouts with different
         ``k_multiple`` stay bit-interconvertible, see ``TableGroup``).
         Historical "group"/"loop" layouts ignore it by construction.
+
+        ``bag_sizes`` (one per table; empty = one id each) makes features
+        multi-hot, sum-pooled; only universal groups look up bags, so a
+        feature with a bag of more than one id must join one.
         """
         tables = tuple(tables)
+        bag_sizes = tuple(int(b) for b in bag_sizes) or (1,) * len(tables)
+        if len(bag_sizes) != len(tables) or min(bag_sizes, default=1) < 1:
+            raise ValueError(f"bag_sizes {bag_sizes} for {len(tables)} tables")
         if mode == "loop":
             groups = tuple(
                 TableGroup("loop", (i,), (t,)) for i, t in enumerate(tables)
             )
-            return cls(tables, groups)
+            return cls(tables, groups, bag_sizes)
         if mode not in ("univ", "group"):
             raise ValueError(f"unknown collection mode {mode!r}")
 
@@ -252,6 +281,7 @@ class EmbeddingCollection:
                             dsub=_gcd_all(s.dsub for s in specs),
                             n_tables=max(s.n_tables for s in specs),
                             k_multiple=k_multiple,
+                            bags=tuple(bag_sizes[i] for i in members),
                         )
                     )
         else:
@@ -275,6 +305,7 @@ class EmbeddingCollection:
                         "univ", tuple(feats), tuple(tables[i] for i in feats),
                         dsub=_gcd_all(s.dsub for s in specs),
                         n_tables=max(s.n_tables for s in specs),
+                        bags=tuple(bag_sizes[i] for i in feats),
                     )
                 )
                 continue
@@ -288,7 +319,14 @@ class EmbeddingCollection:
         # mode="group" keeps the HISTORICAL order (signature insertion +
         # d1-sorted full buckets) so its layout matches PR-3 checkpoints
         # byte for byte — grouped_layout_migration depends on this
-        return cls(tables, tuple(groups))
+        return cls(tables, tuple(groups), bag_sizes)
+
+    def __post_init__(self):
+        for grp in self.groups:
+            if grp.kind != "univ" and any(self.bag_sizes[i] > 1 for i in grp.features):
+                raise ValueError(
+                    f"bag_sizes: features {grp.features} look up bags but sit in a "
+                    f"{grp.kind!r} group; only the universal supertable sums bags")
 
     @staticmethod
     def _partition_univ(feats, tables):
@@ -357,6 +395,21 @@ class EmbeddingCollection:
         return len(self.tables)
 
     @property
+    def multi_hot(self) -> bool:
+        """Some feature looks up a bag of more than one id."""
+        return max(self.bag_sizes, default=1) > 1
+
+    @functools.cached_property
+    def _bag_starts(self) -> np.ndarray:
+        return np.cumsum((0,) + self.bag_sizes)
+
+    def sparse_columns(self, features) -> np.ndarray:
+        """The columns of the sparse input that hold ``features``' ids,
+        each feature's bag in order."""
+        s = self._bag_starts
+        return np.concatenate([np.arange(s[i], s[i + 1]) for i in features])
+
+    @property
     def n_groups(self) -> int:
         return len(self.groups)
 
@@ -369,7 +422,8 @@ class EmbeddingCollection:
         kernel-launch count in tests/test_collection.py so a refactor
         cannot silently reintroduce the per-feature loop."""
         return sum(
-            len(g.features) if g.kind == "loop" else 1 for g in self.groups
+            len(g.features) if g.kind == "loop" else len(g.runs) if g.kind == "univ"
+            else 1 for g in self.groups
         )
 
     @functools.cached_property
@@ -491,36 +545,42 @@ class EmbeddingCollection:
     # --- the hot path -----------------------------------------------------
 
     def group_rows(self, grp: TableGroup, buffers_seq, ids):
-        """Device-side row translation for one universal group:
-        ids (B, Fg) -> (n_cols, B, T) int32.  Cheap int math (pointer
-        gather + multiply-shift hashes) next to the heavy launch; the
-        host twin is ``data.translate.HostTranslator``."""
-        return jnp.concatenate(
-            [
-                _expand_rows(
-                    t.fuse_rows(buffers_seq[f], ids[:, f]),
-                    grp.col_counts[f] // t.fuse_spec.cols,
-                    grp.n_tables,
-                    jnp,
-                )
-                for f, t in enumerate(grp.tables)
-            ],
-            axis=0,
-        )
+        """Device-side row translation for one universal group: ids (B,
+        sum of the members' bags) -> one (n_cols_r, B, bag_r * T) int32
+        tensor per run of ``grp.runs``.  A bag's ids translate as B * bag
+        single ids, and slot ``l * T + t`` holds id l's row in sub-table
+        t; one id a feature gives one (n_cols, B, T) run.  Cheap int math
+        (pointer gather + multiply-shift hashes) next to the heavy
+        launch; the host twin is ``data.translate.HostTranslator``."""
+        B, off, per = ids.shape[0], 0, []
+        for f, t in enumerate(grp.tables):
+            bag = grp.bags[f]
+            rows = _expand_rows(
+                t.fuse_rows(buffers_seq[f], ids[:, off : off + bag].reshape(-1)),
+                grp.col_counts[f] // t.fuse_spec.cols, grp.n_tables, jnp,
+            )  # (n_cols_f, B * bag, T)
+            per.append(rows.reshape(rows.shape[0], B, bag * grp.n_tables))
+            off += bag
+        return tuple(  # adjacent features of one bag length: one run
+            jnp.concatenate([r for _, r in run], axis=0)
+            for _, run in itertools.groupby(zip(grp.bags, per), key=lambda x: x[0]))
 
     def _univ_lookup(self, grp: TableGroup, group_params, rows, use_kernel):
-        """(n_cols, B, T) rows + supertable -> (B, n_cols*dsub)."""
+        """(n_cols, B, T) rows, or the tuple of bag runs ``group_rows``
+        gives, + supertable -> (B, n_cols*dsub)."""
         from repro.kernels import ops as kops
 
+        runs = (rows,) if hasattr(rows, "shape") else tuple(rows)
         # trace span only (HLO metadata — profiler timelines group the
         # fused lookup under one name); no effect on the jaxpr
         with jax.named_scope("emb/fused-lookup"):
             if use_kernel:
-                return kops.cce_lookup(rows, group_params["tables"])
-            return self._univ_lookup_jnp(group_params, rows)
+                return kops.cce_lookup(runs, group_params["tables"])
+            return self._univ_lookup_jnp(group_params, runs)
 
-    def _univ_lookup_jnp(self, group_params, rows):
+    def _univ_lookup_jnp(self, group_params, runs):
         tabs = group_params["tables"]  # (C, T, k, dsub)
+        T = tabs.shape[1]
 
         def col(tab, r):  # (T, k, dsub), (B, T)
             picked = jax.vmap(
@@ -529,9 +589,17 @@ class EmbeddingCollection:
             )(tab, r)  # (T, B, dsub) — sentinel rows contribute exact zero
             return picked.sum(axis=0)
 
-        pieces = jax.vmap(col)(tabs, rows)  # (C, B, dsub)
-        B = rows.shape[1]
-        return jnp.moveaxis(pieces, 0, 1).reshape(B, -1)
+        def one_id(tabs_r, r):  # (c, T, k, dsub), (c, B, T) -> (B, c*dsub)
+            pieces = jax.vmap(col)(tabs_r, r)  # (c, B, dsub)
+            return jnp.moveaxis(pieces, 0, 1).reshape(r.shape[1], -1)
+
+        outs, c0 = [], 0
+        for r in runs:  # a bag's l-th id: slots l*T .. l*T + T of the run
+            tabs_r = tabs[c0 : c0 + r.shape[0]]
+            outs.append(functools.reduce(jnp.add, (
+                one_id(tabs_r, r[..., s : s + T]) for s in range(0, r.shape[2], T))))
+            c0 += r.shape[0]
+        return jnp.concatenate(outs, axis=1)
 
     def _univ_lookup_sharded(self, grp: TableGroup, group_params, rows,
                              use_kernel, *, mesh, model_axis, batch_axes):
@@ -605,7 +673,9 @@ class EmbeddingCollection:
         """All features' embeddings in O(n_groups) heavy lookups — ONE on
         the compressed Criteo config.
 
-        sparse (B, n_features) int32 -> (B, n_features, d2).  Universal
+        sparse (B, sum(bag_sizes)) int32 -> (B, n_features, d2), each
+        feature's embedding the sum over its bag (sparse is (B,
+        n_features) with one id a feature).  Universal
         groups route through the fused Pallas kernel when ``use_kernel``
         (Mosaic on TPU, interpret mode on CPU); ``use_kernel=False`` is
         the masked-gather jnp path — identical math, used as the numerics
@@ -628,6 +698,9 @@ class EmbeddingCollection:
         sharded = mesh is not None and model_axis is not None
         if not sharded and rows is not None and rows.ndim == 4:
             raise ValueError("pre-bucketed 4-d rows require a model mesh")
+        if self.multi_hot and (sharded or rows is not None):
+            raise NotImplementedError(
+                "bag_sizes: bags are looked up from raw ids on one device only")
         outs = [None] * self.n_features
         col_off = 0
         for g, grp in enumerate(self.groups):
@@ -656,7 +729,8 @@ class EmbeddingCollection:
                     col_off += grp.n_cols
                     flat = self._univ_lookup(grp, emb_params[g], grows, use_kernel)
                 else:
-                    ids = jnp.take(sparse, jnp.asarray(grp.features), axis=1)
+                    ids = jnp.take(sparse, jnp.asarray(self.sparse_columns(grp.features)),
+                                   axis=1)
                     grows = self.group_rows(grp, emb_buffers[g], ids)
                     flat = self._univ_lookup(grp, emb_params[g], grows, use_kernel)
                 off = 0
@@ -665,7 +739,8 @@ class EmbeddingCollection:
                     outs[i] = flat[:, off * grp.dsub : (off + n) * grp.dsub]
                     off += n
                 continue
-            ids = jnp.take(sparse, jnp.asarray(grp.features), axis=1)  # (B, Fg)
+            ids = jnp.take(sparse, jnp.asarray(self.sparse_columns(grp.features)),
+                           axis=1)  # (B, Fg)
             if grp.kind == "full":
                 vecs = emb_lib.FullTable.lookup_many(
                     grp.tables, emb_params[g], emb_buffers[g], ids

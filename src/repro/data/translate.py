@@ -50,6 +50,10 @@ class HostTranslator:
 
     def __init__(self, collection: EmbeddingCollection, emb_buffers=None,
                  *, n_shards: int = 1):
+        if collection.multi_hot:
+            raise ValueError(
+                f"HostTranslator translates one id a feature; this collection has "
+                f"bag_sizes {collection.bag_sizes} (bags are translated on the device)")
         self.collection = collection
         self.n_shards = int(n_shards)
         for g in collection.univ_groups:

@@ -30,41 +30,60 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _cce_lookup(idx: jax.Array, tables: jax.Array, b_blk: int, k_blk: int):
-    return _cce_lookup_fwd(idx, tables, b_blk, k_blk)[0]
+def _cce_lookup(runs: tuple, tables: jax.Array, b_blk: int, k_blk: int):
+    return _cce_lookup_fwd(runs, tables, b_blk, k_blk)[0]
 
 
-def _cce_lookup_fwd(idx, tables, b_blk, k_blk):
-    c, B, T = idx.shape
+def _cols(runs) -> list[int]:
+    """Each run's first column in the slab."""
+    return [sum(r.shape[0] for r in runs[:i]) for i in range(len(runs))]
+
+
+def _cat(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _cce_lookup_fwd(runs, tables, b_blk, k_blk):
+    c = tables.shape[0]
+    B = runs[0].shape[1]
     _, _, k, dsub = tables.shape
     B_pad = _round_up(B, b_blk)
     k_pad = _round_up(k, k_blk)
-    idx_p = jnp.pad(idx, ((0, 0), (0, B_pad - B), (0, 0)))
-    tab_p = jnp.pad(tables, ((0, 0), (0, 0), (0, k_pad - k), (0, 0)))
-    out = _cl.cce_lookup_fwd_pallas(
-        idx_p, tab_p, b_blk=b_blk, k_blk=k_blk, interpret=_on_cpu()
-    )  # (B_pad, c, dsub)
-    out = out[:B].reshape(B, c * dsub)
-    return out, (idx, k, jnp.zeros((0,), tables.dtype))
+    tab_p = jnp.swapaxes(
+        jnp.pad(tables, ((0, 0), (0, 0), (0, k_pad - k), (0, 0))), 2, 3)
+    out = _cat([
+        _cl.cce_lookup_fwd_pallas(
+            jnp.swapaxes(jnp.pad(r, ((0, 0), (0, B_pad - B), (0, 0))), 1, 2), tab_p,
+            col0=c0, b_blk=b_blk, k_blk=k_blk, interpret=_on_cpu())
+        for r, c0 in zip(runs, _cols(runs))
+    ])  # (c, dsub, B_pad)
+    out = jnp.transpose(out, (2, 0, 1))[:B].reshape(B, c * dsub)
+    return out, (runs, k, tables.shape[1], jnp.zeros((0,), tables.dtype))
 
 
 def _cce_lookup_bwd(b_blk, k_blk, res, g):
-    idx, k, dtype_token = res
+    runs, k, T, dtype_token = res
     tdtype = dtype_token.dtype
-    c, B, T = idx.shape
+    B = runs[0].shape[1]
+    c = sum(r.shape[0] for r in runs)
     dsub = g.shape[-1] // c
     B_pad = _round_up(B, b_blk)
     k_pad = _round_up(k, k_blk)
-    idx_p = jnp.pad(idx, ((0, 0), (0, B_pad - B), (0, 0)))
     g_p = jnp.pad(
         g.reshape(B, c, dsub).astype(tdtype), ((0, B_pad - B), (0, 0), (0, 0))
     )
     # padded batch rows all point at row 0 — mask their contribution by
     # zeroing the padded gradient rows (jnp.pad already zero-fills).
-    dtab = _cl.cce_lookup_bwd_pallas(
-        idx_p, g_p, k_pad, b_blk=b_blk, k_blk=k_blk, interpret=_on_cpu()
-    )[:, :, :k, :]
-    zero_idx = np.zeros(idx.shape, jax.dtypes.float0)
+    g_t = jnp.transpose(g_p, (1, 2, 0))  # (c, dsub, B_pad)
+    dtab = _cat([
+        _cl.cce_lookup_bwd_pallas(
+            jnp.swapaxes(jnp.pad(r, ((0, 0), (0, B_pad - B), (0, 0))), 1, 2), g_t,
+            k_pad, T, col0=c0, b_blk=b_blk, k_blk=k_blk,
+            interpret=_on_cpu())
+        for r, c0 in zip(runs, _cols(runs))
+    ])
+    dtab = jnp.swapaxes(dtab, 2, 3)[:, :, :k, :]
+    zero_idx = tuple(np.zeros(r.shape, jax.dtypes.float0) for r in runs)
     return (zero_idx, dtab)
 
 
@@ -72,7 +91,7 @@ _cce_lookup.defvjp(_cce_lookup_fwd, _cce_lookup_bwd)
 
 
 def cce_lookup(
-    idx: jax.Array,
+    idx,
     tables: jax.Array,
     *,
     b_blk: int = _cl.DEFAULT_B_BLK,
@@ -83,12 +102,19 @@ def cce_lookup(
 
     Table-count-generic (any T) with the -1 no-op row sentinel (zero
     forward contribution, zero gradient) — the universal-fusion contract
-    (see kernels/cce_lookup.py and DESIGN.md §6)."""
+    (see kernels/cce_lookup.py and DESIGN.md §6).
+
+    Sum-pooled bags: ``idx`` may instead be a sequence of RUNS of adjacent
+    columns, each (c_r, B, bag_r * T) with its own bag length (slot ``l *
+    T + t``: the bag's l-th id in sub-table t), the c_r summing to the
+    slab's c; a column's output is the sum of its bag's rows.  One launch
+    per run, forward and backward."""
+    runs = (idx,) if hasattr(idx, "shape") else tuple(idx)
     k = tables.shape[2]
     if k_blk is None:
         k_blk = min(_cl.DEFAULT_K_BLK, _round_up(k, 128))
-    b_blk = min(b_blk, _round_up(idx.shape[1], 8))
-    return _cce_lookup(idx, tables, b_blk, k_blk)
+    b_blk = min(b_blk, _round_up(runs[0].shape[1], 8))
+    return _cce_lookup(runs, tables, b_blk, k_blk)
 
 
 def pad_stack_tables(slabs, *, k_pad: int | None = None) -> jax.Array:

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 
-def cce_lookup_ref(idx: jax.Array, tables: jax.Array) -> jax.Array:
+def cce_lookup_ref(idx, tables: jax.Array) -> jax.Array:
     """Reference for the fused multi-column gather-sum.
 
     Args:
@@ -19,7 +19,19 @@ def cce_lookup_ref(idx: jax.Array, tables: jax.Array) -> jax.Array:
 
     Returns:
       (B, c * dsub): concat over columns of sum over tables of gathered rows.
+
+    ``idx`` may instead be a sequence of runs of adjacent columns, each
+    (c_r, B, bag * T): a column's output is then the sum over its bag of
+    the one-id lookups, slot ``l * T + t`` being id l's row in table t.
     """
+    if not hasattr(idx, "shape"):
+        T, outs, c0 = tables.shape[1], [], 0
+        for r in idx:
+            tab = tables[c0 : c0 + r.shape[0]]
+            outs.append(sum(cce_lookup_ref(r[..., s : s + T], tab)
+                            for s in range(0, r.shape[2], T)))
+            c0 += r.shape[0]
+        return jnp.concatenate(outs, axis=1)
     c, B, T = idx.shape
     _, _, k, dsub = tables.shape
     # out[i, b] = sum_t [idx >= 0] * tables[i, t, idx[i, b, t]]

@@ -27,7 +27,7 @@ from repro.launch.cache import use_compile_cache
 from repro.models import dlrm, lm
 from repro.obs import RunLog, TelemetryConfig
 from repro.obs.runlog import default_manifest
-from repro.optim import adamw, sgd, cosine_schedule
+from repro.optim import adagrad, adamw, sgd, cosine_schedule
 from repro.optim.remap import remap_opt_state
 from repro.stream import make_step_cell_counter
 from repro.train.freq import IdFrequencyTracker
@@ -216,11 +216,27 @@ def dlrm_tracker(cfg, args):
     return dlrm.make_id_tracker(cfg, stream)
 
 
+#: the optimizers the DLRM step trains with, by ``args.optimizer``: plain
+#: SGD (the paper's default, ``--momentum``) and Adagrad (MLPerf
+#: DLRM-DCNv2's; ``args.eps``, ``args.initial_accumulator``)
+DLRM_OPTIMIZERS = {
+    "sgd": lambda args: sgd(momentum=getattr(args, "momentum", 0.0)),
+    "adagrad": lambda args: adagrad(eps=getattr(args, "eps", 1e-8),
+                                    initial_accumulator=getattr(args, "initial_accumulator", 0.0)),
+}
+
+
 def dlrm_train_step(cfg, args, static_buffers, tracker, telemetry=None):
     """(donated jitted 1-device step, optimizer) — what the 1-device
     trainer runs; a sketch tracker's cell counting rides inside the step
-    (no extra dispatch; the dense tracker contributes nothing)."""
-    optimizer = sgd(momentum=args.momentum)  # paper default: plain SGD
+    (no extra dispatch; the dense tracker contributes nothing).  The
+    optimizer is ``DLRM_OPTIMIZERS[args.optimizer]`` (SGD when unnamed),
+    the gradient clipped to ``args.clip_norm`` (1.0 when unnamed; None
+    does not clip)."""
+    name = getattr(args, "optimizer", "sgd")
+    if name not in DLRM_OPTIMIZERS:
+        raise ValueError(f"optimizer {name!r}: the DLRM step trains {sorted(DLRM_OPTIMIZERS)}")
+    optimizer = DLRM_OPTIMIZERS[name](args)
 
     def lr_fn(step):
         return jnp.float32(args.lr)
@@ -229,7 +245,8 @@ def dlrm_train_step(cfg, args, static_buffers, tracker, telemetry=None):
         return dlrm.bce_loss(p, b, cfg, mb), {}
 
     step = make_train_step(loss_fn, optimizer, lr_fn, static_buffers,
-                           accum=args.accum, telemetry=telemetry,
+                           accum=args.accum, clip_norm=getattr(args, "clip_norm", 1.0),
+                           telemetry=telemetry,
                            sketch_fn=make_step_cell_counter(tracker),
                            donate=True)
     return step, optimizer

@@ -6,6 +6,14 @@ unified sketch framework, incl. CCE); pairwise dot-product interaction;
 top MLP -> 1 logit; Binary Cross-Entropy loss.  Matches the open-source
 DLRM benchmark configuration the paper trains on Criteo.
 
+``interaction="dcnv2"`` is MLPerf's DLRM-DCNv2 (torchrec ``DLRM_DCN``;
+Wang et al. 2021): the bottom MLP's output beside the flattened
+embeddings, ``x0``, goes through ``cross_layers`` low-rank cross layers
+``x_{l+1} = x0 * (W_l (V_l x_l) + b_l) + x_l`` (V_l to ``cross_rank``
+without bias, W_l back with bias) and then the top MLP.  ``bag_sizes``
+makes features multi-hot: each feature's embedding is the sum over a bag
+of a fixed number of ids (MLPerf's Criteo-1TB multi-hot data).
+
 The 26 tables live behind an ``EmbeddingCollection`` (core/collection.py):
 fuse-compatible tables are stacked into grouped supertables and the whole
 forward issues O(n_groups) heavy lookups — for the compressed Criteo
@@ -75,6 +83,21 @@ class DLRMConfig:
     # bit-exactly — see checkpoint_migrations)
     emb_k_multiple: int = 1
     dtype: Any = jnp.float32
+    # ids each feature sum-pools per example; empty = one id per feature.
+    # The sparse input is then (B, sum(bag_sizes)), feature f's bag in the
+    # columns from sum(bag_sizes[:f]) (see EmbeddingCollection)
+    bag_sizes: tuple[int, ...] = ()
+    # "dot" (the pairwise dot interaction) or "dcnv2" (the low-rank cross
+    # network: cross_layers layers of rank cross_rank)
+    interaction: str = "dot"
+    cross_layers: int = 0
+    cross_rank: int = 0
+
+    def __post_init__(self):
+        if self.interaction not in ("dot", "dcnv2"):
+            raise ValueError(f"interaction {self.interaction!r}: 'dot' or 'dcnv2'")
+        if self.interaction == "dcnv2" and (self.cross_layers < 1 or self.cross_rank < 1):
+            raise ValueError("dcnv2 needs cross_layers >= 1 and cross_rank >= 1")
 
     @property
     def n_sparse(self) -> int:
@@ -100,6 +123,7 @@ class DLRMConfig:
             tuple(self._build_table(i) for i in range(self.n_sparse)),
             mode=self.emb_fuse,
             k_multiple=self.emb_k_multiple,
+            bag_sizes=self.bag_sizes,
         )
 
     def table(self, i: int):
@@ -132,6 +156,20 @@ def _apply_mlp(params, x, final_act: bool = False):
     return x
 
 
+def _init_cross(key, width: int, rank: int, n_layers: int, dtype):
+    """Low-rank cross layers: V (width, rank) without bias, W (rank,
+    width) and its bias b, weights normal over the square root of fan-in."""
+    layers = []
+    for i in range(n_layers):
+        kv, kw = jax.random.split(jax.random.fold_in(key, i))
+        layers.append({
+            "v": (jax.random.normal(kv, (width, rank)) / math.sqrt(width)).astype(dtype),
+            "w": (jax.random.normal(kw, (rank, width)) / math.sqrt(rank)).astype(dtype),
+            "b": jnp.zeros((width,), dtype),
+        })
+    return layers
+
+
 def init(key, cfg: DLRMConfig):
     kb, kt, ke = jax.random.split(key, 3)
     params: dict[str, Any] = {
@@ -141,22 +179,33 @@ def init(key, cfg: DLRMConfig):
     # grouped layout: one stacked supertable per fuse-compatible group
     # (slices bit-identical to the legacy per-table init)
     params["emb"], buffers["emb"] = cfg.collection.init(ke)
-    n_pairs = (cfg.n_sparse + 1) * cfg.n_sparse // 2
-    top_in = cfg.bottom_mlp[-1] + n_pairs
+    if cfg.interaction == "dcnv2":
+        top_in = cfg.bottom_mlp[-1] + cfg.n_sparse * cfg.emb_dim
+        params["cross"] = _init_cross(jax.random.fold_in(key, 3), top_in, cfg.cross_rank,
+                                      cfg.cross_layers, cfg.dtype)
+    else:
+        top_in = cfg.bottom_mlp[-1] + (cfg.n_sparse + 1) * cfg.n_sparse // 2
     params["top"] = _init_mlp(kt, (top_in, *cfg.top_mlp), cfg.dtype)
     return params, buffers
 
 
 def interact(params, cfg: DLRMConfig, dense, emb):
     """Everything downstream of the embedding lookup: bottom MLP, pairwise
-    dot interaction, top MLP -> (B,) logits.
+    dot interaction (or the DCNv2 cross network), top MLP -> (B,) logits.
 
-    ``params`` needs only the ``bottom``/``top`` subtrees.  Split out of
-    ``forward`` so the serve engine (serve/dlrm.py) can feed embeddings
-    assembled from its hot cache through the identical math — a cache hit
-    and a supertable lookup produce bit-identical logits."""
+    ``params`` needs only the ``bottom``/``top`` (and ``cross``) subtrees.
+    Split out of ``forward`` so the serve engine (serve/dlrm.py) can feed
+    embeddings assembled from its hot cache through the identical math — a
+    cache hit and a supertable lookup produce bit-identical logits."""
     dense = dense.astype(cfg.dtype)
     x0 = _apply_mlp(params["bottom"], dense, final_act=True)  # (B, emb_dim)
+    if cfg.interaction == "dcnv2":
+        x0 = jnp.concatenate([x0, emb.astype(cfg.dtype).reshape(x0.shape[0], -1)], axis=-1)
+        x = x0
+        with jax.named_scope("dcn/cross"):
+            for layer in params["cross"]:
+                x = x0 * ((x @ layer["v"]) @ layer["w"] + layer["b"]) + x
+        return _apply_mlp(params["top"], x)[:, 0]
     V = jnp.concatenate([x0[:, None, :], emb.astype(cfg.dtype)], axis=1)
     # pairwise dot interactions (upper triangle, no self)
     inter = jnp.einsum("bie,bje->bij", V, V)
@@ -263,12 +312,12 @@ def make_id_tracker(cfg: DLRMConfig, stream=None, *, key: str = "sparse"):
     from repro.stream import IdFrequencyTracker, SketchFrequencyTracker
 
     if stream is None:
-        return IdFrequencyTracker(cfg.vocab_sizes, key=key)
+        return IdFrequencyTracker(cfg.vocab_sizes, key=key, bag_sizes=cfg.bag_sizes)
     tracked = tuple(
         i for i, t in enumerate(cfg.collection.tables) if isinstance(t, CCE)
     )
     return SketchFrequencyTracker(
-        cfg.vocab_sizes, stream, tracked=tracked, key=key
+        cfg.vocab_sizes, stream, tracked=tracked, key=key, bag_sizes=cfg.bag_sizes
     )
 
 

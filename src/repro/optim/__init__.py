@@ -1,5 +1,6 @@
 from repro.optim.optimizers import (  # noqa: F401
     Optimizer,
+    adagrad,
     adamw,
     sgd,
     clip_by_global_norm,

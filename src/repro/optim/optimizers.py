@@ -1,6 +1,7 @@
 """Optimizers as pure (init, update) pairs over param pytrees.
 
-SGD(+momentum) is the paper's choice for DLRM; AdamW is the LM default.
+SGD(+momentum) is the paper's choice for DLRM; Adagrad is MLPerf
+DLRM-DCNv2's; AdamW is the LM default.
 ZeRO-1: `zero1_specs` extends a parameter PartitionSpec tree so optimizer
 moments are additionally sharded over the data axis wherever a dimension
 is divisible — optimizer state then costs 1/(dp·tp) per device while the
@@ -41,6 +42,25 @@ def sgd(momentum: float = 0.0, weight_decay: float = 0.0) -> Optimizer:
         m = jax.tree.map(lambda m, g: momentum * m + g, state["m"], grads)
         new_params = jax.tree.map(lambda p, m_: p - lr * m_, params, m)
         return new_params, {"m": m}
+
+    return Optimizer(init, update)
+
+
+def adagrad(eps: float = 1e-8, initial_accumulator: float = 0.0) -> Optimizer:
+    """Adagrad (Duchi et al. 2011) as PyTorch's ``torch.optim.Adagrad``
+    computes it with no learning-rate decay, which MLPerf DLRM-DCNv2
+    trains with: ``a += g**2; p -= lr * g / (sqrt(a) + eps)``, element-wise
+    on every leaf, embedding tables included."""
+
+    def init(params):
+        return {"a": jax.tree.map(lambda p: jnp.full_like(p, initial_accumulator), params)}
+
+    def update(grads, state, params, lr):
+        a = jax.tree.map(lambda a_, g: a_ + g * g, state["a"], grads)
+        new_params = jax.tree.map(
+            lambda p, g, a_: (p - lr * g / (jnp.sqrt(a_) + eps)).astype(p.dtype),
+            params, grads, a)
+        return new_params, {"a": a}
 
     return Optimizer(init, update)
 

@@ -40,8 +40,8 @@ import jax.numpy as jnp
 Pytree = Any
 
 #: Per-row moment slots of the optimizers in this repo
-#: (sgd-momentum: {"m"}; adamw: {"m", "v"}).
-MOMENT_KEYS = ("m", "v")
+#: (sgd-momentum: {"m"}; adamw: {"m", "v"}; adagrad: {"a"}).
+MOMENT_KEYS = ("m", "v", "a")
 
 POLICIES = ("remap", "reset", "keep")
 

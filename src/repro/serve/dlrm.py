@@ -287,6 +287,10 @@ class DLRMServeEngine:
                  use_kernel: bool | None = None, run_log=None,
                  clock=time.monotonic):
         coll = cfg.collection
+        if coll.multi_hot:
+            raise ValueError(
+                f"DLRMServeEngine serves one id a feature; this config has bag_sizes "
+                f"{coll.bag_sizes} (multi-hot bags are looked up in training only)")
         unfused = sorted({g.kind for g in coll.groups if g.kind != "univ"})
         if unfused:
             raise ValueError(

@@ -34,30 +34,37 @@ import numpy as np
 from repro.obs.trace import span
 
 
-def cell_count_fn(sketches):
-    """PURE (B, F) int32 -> (F, depth, width) int32 cell-increment counter
+def cell_count_fn(sketches, col_sketch):
+    """PURE (B, n_cols) int32 -> (F, depth, width) int32 cell-increment counter
     over ``sketches`` (the tracked features' ``CountMinSketch`` objects,
     which must share width/depth — one ``StreamConfig`` builds them, so
     they do).  Not jitted: the caller either wraps it (the standalone
     dispatcher below) or INLINES it into an already-jitted program — the
     train step embeds it via ``make_step_cell_counter`` so sketch tracking
-    adds ZERO extra device dispatches (DESIGN.md §6)."""
+    adds ZERO extra device dispatches (DESIGN.md §6).
+
+    ``col_sketch`` gives each input column's sketch (its index in
+    ``sketches``): a column's ids are hashed with, and counted into, its
+    feature's sketch, so every id of a multi-hot bag counts (one column a
+    feature without bags: ``arange(F)``)."""
     widths = {s.width for s in sketches}
     depths = {s.depth for s in sketches}
     if len(widths) != 1 or len(depths) != 1:
         raise ValueError("tracked sketches must share width/depth")
     (width,), (depth,) = widths, depths
     n_feat = len(sketches)
-    a = jnp.asarray(np.stack([s.a for s in sketches]))  # (F, depth) uint32
-    b = jnp.asarray(np.stack([s.b for s in sketches]))
+    col_sketch = np.asarray(col_sketch)
+    a = jnp.asarray(np.stack([s.a for s in sketches])[col_sketch])  # (n_cols, depth) uint32
+    b = jnp.asarray(np.stack([s.b for s in sketches])[col_sketch])
+    # each column's (feature, row) plane: where its cells start in the delta
+    base = ((col_sketch[:, None] * depth + np.arange(depth)) * width).astype(np.uint32)
     shift = int(sketches[0].shift)
 
-    def count_cells(sparse):  # (B, F) int32
-        x = sparse.T.astype(jnp.uint32)  # (F, B)
+    def count_cells(sparse):  # (B, n_cols) int32
+        x = sparse.T.astype(jnp.uint32)  # (n_cols, B)
         cells = (a[:, :, None] * x[:, None, :] + b[:, :, None]) >> shift
         # one flat scatter-add across every (feature, row) plane
-        base = jnp.arange(n_feat * depth, dtype=jnp.uint32)[:, None] * width
-        flat = (cells.reshape(n_feat * depth, -1) + base).reshape(-1)
+        flat = (cells + base[:, :, None]).reshape(-1)
         delta = jnp.zeros(n_feat * depth * width, jnp.int32).at[
             flat.astype(jnp.int32)
         ].add(1)
@@ -66,11 +73,11 @@ def cell_count_fn(sketches):
     return count_cells
 
 
-def make_cell_counter(sketches):
+def make_cell_counter(sketches, col_sketch):
     """Standalone jitted dispatcher around ``cell_count_fn`` — the
     tracker's own fallback path when the train step does not embed the
     counter (one extra dispatch per batch)."""
-    return jax.jit(cell_count_fn(sketches))
+    return jax.jit(cell_count_fn(sketches, col_sketch))
 
 
 def make_step_cell_counter(tracker):
@@ -78,14 +85,15 @@ def make_step_cell_counter(tracker):
     ``train.loop.make_train_step``: microbatch dict -> (F_tracked, depth,
     width) int32 cell delta, computed INSIDE the jitted step (selecting
     the tracked sparse columns with the same hash coefficients the host
-    sketch uses, so in-step cells == host cells bit for bit).  Returns
-    None when the tracker has no sketch-backed features (dense tracker,
-    or nothing tracked) — the step then carries no delta."""
+    sketch uses, so in-step cells == host cells bit for bit; every id of a
+    tracked feature's bag counts).  Returns None when the tracker has no
+    sketch-backed features (dense tracker, or nothing tracked) — the step
+    then carries no delta."""
     tracked = getattr(tracker, "tracked", None)
     if not tracked:
         return None
-    fn = cell_count_fn([tracker.features[f].cms for f in tracked])
-    cols = np.asarray(tracked)
+    fn = cell_count_fn([tracker.features[f].cms for f in tracked], tracker.col_sketch)
+    cols = tracker.tracked_cols
     key = tracker.key
 
     def count(microbatch):

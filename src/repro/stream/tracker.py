@@ -58,18 +58,21 @@ class IdFrequencyTracker:
     row; fine for small vocabs and for tests, defeats CCE's memory point
     at production vocab sizes)."""
 
-    def __init__(self, vocab_sizes: Sequence[int], key: str = "sparse"):
+    def __init__(self, vocab_sizes: Sequence[int], key: str = "sparse",
+                 bag_sizes: Sequence[int] = ()):
         self.key = key
         self.counts = [np.zeros(v, np.int64) for v in vocab_sizes]
+        self.bags = bag_columns(len(self.counts), bag_sizes)
 
     def observe(self, batch: dict) -> None:
         """Accumulate one (un-reshaped) batch: ``batch[self.key]`` is
-        (B, n_features) int.  Runs on the training hot path, so the
+        (B, n_features) int, or (B, sum(bag_sizes)) with every id of a
+        feature's bag counted.  Runs on the training hot path, so the
         update is O(batch) — never O(vocab) (a full-vocab bincount per
         step would dwarf the step itself on 100M-row tables)."""
-        sparse = np.asarray(batch[self.key]).reshape(-1, len(self.counts))
+        sparse = np.asarray(batch[self.key]).reshape(-1, self.bags[-1])
         for f, c in enumerate(self.counts):
-            np.add.at(c, sparse[:, f], 1)
+            np.add.at(c, sparse[:, self.bags[f] : self.bags[f + 1]].ravel(), 1)
 
     def sample_ids(self, seed: int, feature: int, n: int) -> np.ndarray | None:
         """Draw ``n`` ids ~ the observed frequency of ``feature``."""
@@ -95,6 +98,16 @@ class IdFrequencyTracker:
         return sum(c.nbytes for c in self.counts)
 
 
+def bag_columns(n_features: int, bag_sizes: Sequence[int] = ()) -> np.ndarray:
+    """(n_features + 1,) starts of each feature's columns in a sparse
+    batch: feature f's bag is columns ``[s[f], s[f + 1])`` (one column a
+    feature without bags)."""
+    bags = tuple(bag_sizes) or (1,) * n_features
+    if len(bags) != n_features or min(bags, default=1) < 1:
+        raise ValueError(f"bag_sizes {tuple(bag_sizes)} for {n_features} features")
+    return np.cumsum((0,) + bags)
+
+
 def _head_counters(heads) -> dict[str, int]:
     """SpaceSaving's running trace counters, summed over ``heads``."""
     return {k: sum(getattr(h, k) for h in heads)
@@ -111,12 +124,23 @@ class SketchFrequencyTracker:
         *,
         tracked: Sequence[int] | None = None,
         key: str = "sparse",
+        bag_sizes: Sequence[int] = (),
     ):
         self.key = key
         self.config = config
         self.vocab_sizes = tuple(int(v) for v in vocab_sizes)
         n = len(self.vocab_sizes)
         self.tracked = tuple(sorted(tracked)) if tracked is not None else tuple(range(n))
+        # multi-hot features: every id of a tracked feature's bag counts.
+        # ``tracked_cols`` are the sparse columns the fold reads, and
+        # ``col_sketch`` the tracked feature (its index in ``tracked``) of each
+        self.bag_sizes = tuple(int(b) for b in bag_sizes) or (1,) * n
+        self.bags = bag_columns(n, self.bag_sizes)
+        self.col_sketch = np.repeat(np.arange(len(self.tracked)),
+                                    [self.bag_sizes[f] for f in self.tracked])
+        self.tracked_cols = np.concatenate(
+            [np.arange(self.bags[f], self.bags[f + 1]) for f in self.tracked]
+            or [np.zeros(0, np.int64)])
         self.features: list[FeatureSketch | None] = [None] * n
         for f in self.tracked:
             self.features[f] = FeatureSketch(
@@ -131,7 +155,7 @@ class SketchFrequencyTracker:
             from repro.stream.device import AsyncFolder, make_cell_counter
 
             self._cell_counter = make_cell_counter(
-                [self.features[f].cms for f in self.tracked]
+                [self.features[f].cms for f in self.tracked], self.col_sketch
             )
             self._folder = AsyncFolder(self._fold)
 
@@ -156,9 +180,9 @@ class SketchFrequencyTracker:
         synchronously otherwise — same FIFO per-batch fold either way, so
         flushed state stays a pure function of the batch sequence and
         restart-exactness is preserved)."""
-        sparse = np.asarray(batch[self.key]).reshape(-1, len(self.features))
+        sparse = np.asarray(batch[self.key]).reshape(-1, self.bags[-1])
         if delta is not None and self.tracked:
-            cols = np.ascontiguousarray(sparse[:, list(self.tracked)])
+            cols = np.ascontiguousarray(sparse[:, self.tracked_cols])
             if self._folder is not None:
                 self._folder.submit((delta, cols))  # device_get off-thread
             else:
@@ -166,11 +190,11 @@ class SketchFrequencyTracker:
         elif self._folder is not None:
             import jax.numpy as jnp
 
-            cols = np.ascontiguousarray(sparse[:, list(self.tracked)])
+            cols = np.ascontiguousarray(sparse[:, self.tracked_cols])
             delta = self._cell_counter(jnp.asarray(cols, jnp.int32))
             self._folder.submit((delta, cols))  # device_get happens off-thread
         else:
-            self._fold_heads(sparse[:, list(self.tracked)], into_sketch=True)
+            self._fold_heads(sparse[:, self.tracked_cols], into_sketch=True)
         self.batches_seen += 1
         w = self.config.window
         if w and self.batches_seen % w == 0:
@@ -197,16 +221,23 @@ class SketchFrequencyTracker:
                                   for k, v in _head_counters(heads).items()})
 
     def _fold_heads(self, cols: np.ndarray, *, into_sketch: bool = False) -> None:
-        """Every tracked feature's head and ring update for one (B,
-        F_tracked) batch: one ``count_rows`` over all the features, then
-        each feature's ``ingest_counted``.  ``into_sketch`` adds the absent
-        ids' mass to the sketches too, on the path with no cell delta."""
-        uids, counts, bounds = count_rows(cols.T)
-        bounds = bounds.tolist()
-        for j, f in enumerate(self.tracked):
-            lo, hi = bounds[j], bounds[j + 1]
-            self.features[f].ingest_counted(cols[:, j], uids[lo:hi], counts[lo:hi],
-                                            into_sketch=into_sketch)
+        """Every tracked feature's head and ring update for one batch of
+        the tracked bag columns (``tracked_cols``): a feature's ids are
+        its whole bag, example by example, and the features of one bag
+        length share one ``count_rows``; then each feature's
+        ``ingest_counted``.  ``into_sketch`` adds the absent ids' mass to
+        the sketches too, on the path with no cell delta."""
+        bags = [self.bag_sizes[f] for f in self.tracked]
+        starts = np.cumsum([0] + bags)
+        for bag in sorted(set(bags)):
+            js = [j for j, b in enumerate(bags) if b == bag]
+            ids = np.stack([cols[:, starts[j] : starts[j + 1]].ravel() for j in js])
+            uids, counts, bounds = count_rows(ids)
+            bounds = bounds.tolist()
+            for r, j in enumerate(js):
+                lo, hi = bounds[r], bounds[r + 1]
+                self.features[self.tracked[j]].ingest_counted(
+                    ids[r], uids[lo:hi], counts[lo:hi], into_sketch=into_sketch)
 
     def _close_window(self) -> None:
         """Window boundary: snapshot trigger statistics, then decay."""
@@ -285,7 +316,7 @@ class SketchFrequencyTracker:
         fresh = SketchFrequencyTracker(
             self.vocab_sizes,
             dataclasses.replace(self.config, async_fold=False),
-            tracked=self.tracked, key=self.key,
+            tracked=self.tracked, key=self.key, bag_sizes=self.bag_sizes,
         )
         return fresh.state_tree()
 

@@ -88,7 +88,7 @@ def make_train_step(
     static_buffers,
     *,
     accum: int = 1,
-    clip_norm: float = 1.0,
+    clip_norm: float | None = 1.0,
     compress_grads: bool = False,
     grad_specs: Pytree | None = None,
     sketch_fn: Callable[[Pytree], jax.Array] | None = None,
@@ -98,6 +98,8 @@ def make_train_step(
     """loss_fn(params, buffers, microbatch) -> (loss, metrics dict).
 
     The returned step expects batch leaves shaped (accum, micro, ...).
+    The averaged gradient is clipped to a global norm of ``clip_norm``
+    (``None``: not clipped).
     ``grad_specs`` (optional PartitionSpec tree) shards the gradient
     accumulators over the data axis (ZeRO-2-style): each microbatch's
     cross-data reduction then lowers to a reduce-scatter instead of a full
@@ -181,7 +183,10 @@ def make_train_step(
         if compress_grads:
             grads, err = compressed_grad_transform(grads, err)
 
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
+        if clip_norm is None:  # no clip: the norm is still reported
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads)))
+        else:
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
         lr = lr_fn(state.step)
         new_params, new_opt = optimizer.update(grads, state.opt, state.params, lr)
         new_state = TrainState(

@@ -429,7 +429,7 @@ def test_host_translated_rows_match_device_bitexact():
     rows = tr.rows(sparse)
     assert rows.shape == (33, coll.rows_n_cols, coll.rows_n_tables)
     # host rows == device rows, bit for bit
-    dev = coll.group_rows(coll.groups[0], buffers["emb"][0], jnp.asarray(sparse))
+    (dev,) = coll.group_rows(coll.groups[0], buffers["emb"][0], jnp.asarray(sparse))
     np.testing.assert_array_equal(np.moveaxis(rows, 0, 1), np.asarray(dev))
     # lookup through host rows == device-translated lookup, bit for bit
     for uk in (True, False):
@@ -458,7 +458,7 @@ def test_host_translation_clamps_out_of_range_ids_like_device():
         [np.array([0, v - 1, v, v + 99]) for v in cfg.vocab_sizes], axis=1
     ).astype(np.int32)
     rows = tr.rows(sparse)
-    dev = jax.jit(
+    (dev,) = jax.jit(
         lambda ids: coll.group_rows(coll.groups[0], buffers["emb"][0], ids)
     )(jnp.asarray(sparse))
     np.testing.assert_array_equal(np.moveaxis(rows, 0, 1), np.asarray(dev))
@@ -483,7 +483,7 @@ def test_host_translation_tracks_transitions():
         [rng.integers(0, v, 17) for v in cfg.vocab_sizes], axis=1
     ).astype(np.int32)
     rows = tr.rows(sparse)
-    dev = coll.group_rows(coll.groups[0], new_b[0], jnp.asarray(sparse))
+    (dev,) = coll.group_rows(coll.groups[0], new_b[0], jnp.asarray(sparse))
     np.testing.assert_array_equal(np.moveaxis(rows, 0, 1), np.asarray(dev))
     a = coll.lookup_all(new_p, new_b, jnp.asarray(sparse), use_kernel=True)
     b = coll.lookup_all(new_p, new_b, None, use_kernel=True, rows=jnp.asarray(rows))
@@ -884,3 +884,57 @@ def test_collection_transition_equals_per_table_transition():
         np.testing.assert_array_equal(
             np.asarray(got_b["hs"]), np.asarray(want_b["hs"])
         )
+
+
+# --- sum-pooled multi-hot bags ---------------------------------------------
+
+
+def _bag_config(**kw):
+    return DLRMConfig(vocab_sizes=(900, 7, 5000, 300), emb_dim=16, emb_method="cce",
+                      emb_param_cap=512, emb_c=4, bag_sizes=(3, 1, 1, 2), **kw)
+
+
+def test_bags_sum_their_ids_in_one_launch_per_run():
+    """Each feature's embedding is the sum over its bag, through the fused
+    kernel and the jnp path alike; adjacent features of one bag length
+    share a launch (fwd and bwd: one pallas_call per run each)."""
+    from repro.analysis import count_primitive
+
+    cfg = _bag_config()
+    coll = cfg.collection
+    assert [g.runs for g in coll.groups] == [((4, 3), (8, 1), (4, 2))]
+    assert coll.n_lookup_launches == 3
+    params, buffers = dlrm.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    sparse = jnp.asarray(np.concatenate(
+        [rng.integers(0, v, (13, n)) for v, n in zip(cfg.vocab_sizes, cfg.bag_sizes)], 1),
+        jnp.int32)
+    per_p = coll.unstack_params(params["emb"])
+    per_b = coll.unstack_buffers(buffers["emb"])
+    cols = np.cumsum((0,) + cfg.bag_sizes)
+    want = jnp.stack([sum(coll.tables[f].lookup(per_p[f], per_b[f], sparse[:, j])
+                          for j in range(cols[f], cols[f + 1]))
+                      for f in range(coll.n_features)], axis=1)
+    for use_kernel in (True, False):
+        got = jax.jit(lambda p: coll.lookup_all(p, buffers["emb"], sparse,
+                                                use_kernel=use_kernel))(params["emb"])
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+    grad = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+        coll.lookup_all(p, buffers["emb"], sparse, use_kernel=True))))(params["emb"])
+    assert count_primitive(grad, "pallas_call") == 2 * coll.n_lookup_launches
+
+
+def test_bags_outside_the_universal_group_are_refused():
+    with pytest.raises(ValueError, match="bag_sizes"):
+        _bag_config(emb_fuse="loop").collection
+    coll = _bag_config().collection
+    with pytest.raises(NotImplementedError, match="bag_sizes"):
+        coll.lookup_all(None, None, None, rows=jnp.zeros((2, coll.rows_n_cols, 2), jnp.int32))
+
+
+def test_host_translator_refuses_bags():
+    """Host translation stays one id a feature until a cell needs bags."""
+    from repro.data import HostTranslator
+
+    with pytest.raises(ValueError, match="bag_sizes"):
+        HostTranslator(_bag_config().collection)

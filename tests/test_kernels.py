@@ -166,3 +166,39 @@ def test_cce_logits_ref_consistency():
     want = h @ E.T
     got = ref.cce_logits_ref(h, idx, tables)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dsub", [4, 32])
+def test_cce_lookup_bags_match_ref(dsub):
+    """Sum-pooled bags: runs of columns with their own bag lengths (1
+    among them), rows with -1 sentinels, forward and backward against
+    the sum of single-id lookups (``ref.cce_lookup_ref`` over runs)."""
+    key = jax.random.PRNGKey(5)
+    T, k, B = 2, 70, 19
+    shape = [(2, 3), (1, 1), (3, 5)]  # (columns, bag) of each run
+    tables = jax.random.normal(key, (sum(c for c, _ in shape), T, k, dsub), jnp.float32)
+    runs = [jax.random.randint(jax.random.fold_in(key, i), (c, B, bag * T), -1, k)
+            for i, (c, bag) in enumerate(shape)]
+    runs[2] = runs[2].at[:, :, 1::T].set(-1)  # a T=1 member: every helper slot a sentinel
+
+    @jax.jit
+    def fused(t):
+        return ops.cce_lookup(runs, t, b_blk=8, k_blk=128)
+
+    want = jax.jit(lambda t: ref.cce_lookup_ref(runs, t))
+    out = fused(tables)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want(tables)), rtol=1e-5, atol=1e-5)
+    co = jax.random.normal(jax.random.fold_in(key, 9), out.shape)
+    g1 = jax.jit(jax.grad(lambda t: jnp.sum(fused(t) * co)))(tables)
+    g2 = jax.jit(jax.grad(lambda t: jnp.sum(want(t) * co)))(tables)
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-4, atol=1e-4)
+    assert float(jnp.abs(g1[3:, 1]).max()) == 0.0  # sentinel sub-table: no gradient
+
+
+def test_cce_lookup_one_run_of_bag_one_is_the_one_hot_lookup():
+    """A single run whose bag is 1 is the (c, B, T) lookup, bit for bit."""
+    key = jax.random.PRNGKey(6)
+    idx = jax.random.randint(key, (3, 21, 2), -1, 40)
+    tables = jax.random.normal(key, (3, 2, 40, 8), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(ops.cce_lookup([idx], tables)),
+                                  np.asarray(ops.cce_lookup(idx, tables)))

@@ -6,7 +6,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro.optim import adamw, sgd, clip_by_global_norm, cosine_schedule
+from repro.optim import adagrad, adamw, sgd, clip_by_global_norm, cosine_schedule
 from repro.optim.compression import (
     compressed_grad_transform,
     init_error_feedback,
@@ -114,3 +114,24 @@ def test_compression_preserves_convergence():
         dq, err = compressed_grad_transform(g, err)
         w = w - 0.1 * dq["w"]
     np.testing.assert_allclose(np.asarray(w), np.asarray(target), atol=1e-2)
+
+
+def test_adagrad_matches_hand_update():
+    """a += g**2; p -= lr * g / (sqrt(a) + eps), element-wise on every leaf,
+    from the initial accumulator; a zero gradient leaves its element."""
+    opt = adagrad(eps=1e-3, initial_accumulator=0.5)
+    params = {"w": jnp.array([[1.0, -2.0], [0.5, 3.0]]), "b": jnp.array([0.1, 0.2, 0.3])}
+    grads = [{"w": jnp.array([[0.3, 0.0], [-1.0, 2.0]]), "b": jnp.array([1.0, -0.5, 0.0])},
+             {"w": jnp.array([[-0.2, 0.4], [0.1, 0.0]]), "b": jnp.array([0.0, 0.5, 2.0])}]
+    state = opt.init(params)
+    p_np = {k: np.asarray(v, np.float64) for k, v in params.items()}
+    a_np = {k: np.full(v.shape, 0.5) for k, v in p_np.items()}
+    for g in grads:
+        params, state = opt.update(g, state, params, 0.1)
+        for k in p_np:
+            gk = np.asarray(g[k], np.float64)
+            a_np[k] += gk * gk
+            p_np[k] -= 0.1 * gk / (np.sqrt(a_np[k]) + 1e-3)
+    for k in p_np:
+        np.testing.assert_allclose(np.asarray(params[k]), p_np[k], rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(state["a"][k]), a_np[k], rtol=1e-6)

@@ -326,3 +326,12 @@ def test_hot_cache_build_drops_bad_ids(state):
     slots, hit = cache.slots(np.array([[5, 0, 0, 0, 0], [7, 0, 0, 0, 0]]))
     assert hit[0, 0] and not hit[1, 0]
     assert slots[0, 0] == 1 and slots[1, 0] == -1
+
+def test_engine_refuses_bags():
+    """Serving stays one id a feature until a serve cell needs bags."""
+    import dataclasses
+
+    cfg = dataclasses.replace(reduced(emb_method="cce", cap=512), bag_sizes=(2, 1, 1, 1, 1))
+    params, buffers = dlrm.init(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(ValueError, match="bag_sizes"):
+        DLRMServeEngine(params, buffers, cfg)

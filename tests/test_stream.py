@@ -102,7 +102,7 @@ def test_cms_device_cells_match_host_cells():
     from repro.stream.device import make_cell_counter
 
     cms = CountMinSketch(width=1 << 9, depth=3, seed=7)
-    counter = make_cell_counter([cms])
+    counter = make_cell_counter([cms], [0])
     ids = np.random.default_rng(1).integers(0, 1_000_000, 4096)
     delta = np.asarray(counter(jnp.asarray(ids[:, None], jnp.int32)))[0]
     ref = np.zeros((3, 1 << 9), np.int64)
@@ -894,3 +894,65 @@ def test_trigger_survives_tracked_feature_count_change():
     assert not ev.fire and ev.drift == 0.0  # baseline reset, no IndexError
     ev = tg.update(_stats(3.0, two), step=3)
     assert ev.drift == pytest.approx(0.0)  # baseline re-established
+
+
+# --- multi-hot bags ---------------------------------------------------------
+
+
+def _bag_batch(vocab, bags, B, rng):
+    return np.concatenate([rng.zipf(1.2, (B, n)) % v for v, n in zip(vocab, bags)],
+                          axis=1).astype(np.int32)
+
+
+def test_in_step_counter_counts_every_id_of_a_bag():
+    """The step's cell delta of a tracked feature is the bincount of ALL
+    its bag columns' ids, hashed with that feature's rows."""
+    from repro.stream import make_step_cell_counter
+
+    vocab, bags = (50, 3000, 70_000, 9), (3, 1, 4, 2)
+    trk = SketchFrequencyTracker(vocab, StreamConfig(width=1 << 8, depth=3),
+                                 tracked=(0, 2, 3), bag_sizes=bags)
+    sparse = _bag_batch(vocab, bags, 64, np.random.default_rng(4))
+    got = np.asarray(jax.jit(make_step_cell_counter(trk))({"sparse": jnp.asarray(sparse)}))
+    cols = np.cumsum((0,) + bags)
+    for j, f in enumerate(trk.tracked):
+        cms = trk.features[f].cms
+        cells = cms.cells(sparse[:, cols[f]:cols[f + 1]].ravel())
+        want = np.stack([np.bincount(cells[r], minlength=cms.width) for r in range(3)])
+        np.testing.assert_array_equal(got[j], want)
+
+
+@pytest.mark.parametrize("async_fold", [False, True])
+def test_bag_fold_offers_every_id_of_a_bag(async_fold):
+    """With bags, a tracked feature's sketch, head and mass take every id
+    of its bag: the same state as a single-id tracker of that feature (the
+    same hash rows) fed the feature's bag ids flattened, example by
+    example, each step's delta counted in the step."""
+    from repro.stream import make_step_cell_counter
+
+    vocab, bags = (40, 3000, 9, 500_000), (3, 1, 2, 5)
+    scfg = StreamConfig(width=1 << 9, depth=3, heavy=32, ring=64, decay=0.9,
+                        window=4, async_fold=async_fold)
+    trk = SketchFrequencyTracker(vocab, scfg, tracked=(0, 1, 3), bag_sizes=bags)
+    flat = {f: SketchFrequencyTracker(vocab[: f + 1], scfg, tracked=(f,)) for f in trk.tracked}
+    rng = np.random.default_rng(5)
+    cols = np.cumsum((0,) + bags)
+    counters = {id(t): jax.jit(make_step_cell_counter(t)) for t in [trk, *flat.values()]}
+
+    def feed(t, sparse):
+        t.observe({"sparse": sparse}, delta=counters[id(t)]({"sparse": jnp.asarray(sparse)}))
+
+    for _ in range(9):
+        sparse = _bag_batch(vocab, bags, 32, rng)
+        feed(trk, sparse)
+        for f, fl in flat.items():
+            one = np.zeros((32 * bags[f], f + 1), np.int32)
+            one[:, f] = sparse[:, cols[f]:cols[f + 1]].ravel()
+            feed(fl, one)
+    trk.flush()
+    for f, fl in flat.items():
+        fl.flush()
+        a, b = trk.features[f], fl.features[f]
+        assert a.mass == b.mass > 0
+        for x, y in zip(a.state_tree(), b.state_tree()):
+            np.testing.assert_array_equal(x, y)

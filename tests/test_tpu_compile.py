@@ -101,6 +101,33 @@ def test_cce_lookup_compiles_at_criteo_shapes(one_chip, mosaic, direction):
             lambda tt: ops.cce_lookup(i, tt).sum())(t), idx, tab)
 
 
+#: MLPerf DLRM-DCNv2's Criteo-1TB tables and bags, CCE at 65,536 parameters
+#: a table (the ``dlrm_dcnv2_criteo_1tb`` benchmark configuration)
+CRITEO_1TB = dict(
+    vocab_sizes=(40000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 40000000,
+                 3067956, 405282, 10, 2209, 11938, 155, 4, 976, 14, 40000000, 40000000,
+                 40000000, 590152, 12973, 108, 36),
+    bag_sizes=(3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10,
+               3, 1, 1),
+    emb_dim=128, emb_method="cce", emb_param_cap=65536, emb_c=4)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_bag_lookup_compiles_at_criteo_1tb_shapes(one_chip, mosaic, direction):
+    """The multi-hot lookup at dsub 32, one launch per run of equal bag
+    length (bags of 1 to 100 ids), at MLPerf's per-chip batch of 8192."""
+    (grp,) = dlrm.DLRMConfig(**CRITEO_1TB).collection.groups
+    assert (grp.n_cols, grp.dsub, grp.k_pad) == (104, 32, 256)
+    runs = tuple(jax.ShapeDtypeStruct((n, 8192, bag * grp.n_tables), jnp.int32,
+                                      sharding=one_chip) for n, bag in grp.runs)
+    tab = jax.ShapeDtypeStruct((grp.n_cols, grp.n_tables, grp.k_pad, grp.dsub),
+                               jnp.float32, sharding=one_chip)
+    if direction == "fwd":
+        _compile(ops.cce_lookup, runs, tab)
+    else:
+        _compile(lambda r, t: jax.grad(lambda tt: ops.cce_lookup(r, tt).sum())(t), runs, tab)
+
+
 def test_kmeans_assign_compiles_at_criteo_shapes(one_chip, mosaic):
     """One ``assign_all`` chunk (``emb_cluster_chunk`` ids) against one
     column's codebook — the transition's full-vocabulary pass."""

@@ -144,7 +144,7 @@ def test_enqueue_wait_spans_count_blocked_submits(tmp_path):
     the one a per-feature synchronous fold of the same batches gives."""
     scfg = StreamConfig(width=1 << 9, depth=3, heavy=16, ring=128)
     trk = SketchFrequencyTracker((100, 200), scfg, tracked=(0, 1))
-    count = make_cell_counter([trk.features[f].cms for f in trk.tracked])
+    count = make_cell_counter([trk.features[f].cms for f in trk.tracked], trk.col_sketch)
     rng = np.random.default_rng(0)
     batches = [rng.integers(0, 100, (32, 2)) for _ in range(10)]
     deltas = [count(jnp.asarray(b, jnp.int32)) for b in batches]

@@ -301,3 +301,53 @@ def test_failure_injector_fires_once():
     with pytest.raises(RuntimeError):
         fi.maybe_fail(3)
     fi.maybe_fail(3)  # second pass: already fired
+
+
+@pytest.mark.parametrize("clip_norm", [0.05, None])
+def test_clip_norm_reaches_the_step(clip_norm):
+    """A clip other than 1.0 (and None, no clip) reaches the update: one
+    SGD step moves the params by lr times the clipped (or raw) gradient."""
+    cfg = dlrm_criteo.reduced(emb_method="cce", cap=512)
+    params, buffers = dlrm.init(jax.random.PRNGKey(0), cfg)
+    dyn, static = split_buffers(buffers)
+
+    def loss_fn(p, b, mb):
+        return dlrm.bce_loss(p, b, cfg, mb), {}
+
+    step = make_train_step(loss_fn, sgd(), lambda s: jnp.float32(1.0), static,
+                           clip_norm=clip_norm)
+    data = next(clickstream_batches(ClickstreamConfig(vocab_sizes=cfg.vocab_sizes), 32))
+    batch = {k: np.asarray(v)[None] for k, v in data.items() if k != "step"}
+    new, m = jax.jit(step)(init_state(params, sgd(), dyn), batch)
+    moved = np.sqrt(sum(float(jnp.sum((a - b) ** 2)) for a, b in
+                        zip(jax.tree.leaves(params), jax.tree.leaves(new.params))))
+    assert float(m["gnorm"]) > 0.05  # the clip bites
+    want = float(m["gnorm"]) if clip_norm is None else clip_norm
+    np.testing.assert_allclose(moved, want, rtol=1e-4)
+
+
+def test_entry_point_picks_the_optimizer_by_name():
+    """``dlrm_train_step`` builds ``DLRM_OPTIMIZERS[args.optimizer]`` and
+    hands ``args.clip_norm`` to the step; an unknown name is refused."""
+    import argparse
+
+    from repro.launch import train
+
+    assert set(train.DLRM_OPTIMIZERS) == {"sgd", "adagrad"}
+    cfg = dlrm_criteo.reduced(emb_method="cce", cap=512)
+    params, buffers = dlrm.init(jax.random.PRNGKey(0), cfg)
+    dyn, static = split_buffers(buffers)
+    args = argparse.Namespace(accum=1, optimizer="adagrad", lr=0.01, eps=1e-8,
+                              initial_accumulator=0.0, clip_norm=None)
+    step, opt = train.dlrm_train_step(cfg, args, static, None)
+    w0 = np.asarray(params["top"][0]["w"])  # the step donates the state
+    state = init_state(params, opt, dyn)
+    assert set(state.opt) == {"a"}
+    data = next(clickstream_batches(ClickstreamConfig(vocab_sizes=cfg.vocab_sizes), 32))
+    new, _ = step(state, {k: np.asarray(v)[None] for k, v in data.items() if k != "step"})
+    # Adagrad from a zero accumulator moves every element with a gradient by lr
+    moved = np.abs(np.asarray(new.params["top"][0]["w"]) - w0)
+    np.testing.assert_allclose(moved.max(), 0.01, rtol=1e-3)
+    with pytest.raises(ValueError, match="adagrad"):
+        train.dlrm_train_step(cfg, argparse.Namespace(accum=1, optimizer="lamb", lr=0.1),
+                              static, None)

@@ -168,6 +168,16 @@ def test_remap_opt_state_policies():
         remap_opt_state(opt, None, policy="bogus")
 
 
+def test_remap_opt_state_carries_adagrad_accumulators():
+    """Adagrad's per-element accumulator is a moment slot: a transition
+    remaps (or resets) it with the table it belongs to."""
+    from repro.optim import adagrad
+
+    opt = adagrad(initial_accumulator=0.5).init({"w": jnp.ones(3)})
+    out = remap_opt_state(opt, lambda mom, slot: jax.tree.map(lambda x: 2 * x, mom))
+    assert float(out["a"]["w"][0]) == 1.0
+
+
 def test_cluster_tables_remaps_and_resets():
     cfg = dlrm_criteo.reduced(emb_method="cce", cap=512)
     coll = cfg.collection
